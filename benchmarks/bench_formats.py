@@ -20,10 +20,8 @@ Geometries mirror the figures: fig8 (small fixed-size molecules, feature
 width sweep axis), fig9 (larger uniform matrices), fig10 (mixed sizes — the
 regime the paper CALLS skewed, though its row degrees stay near-uniform)
 plus ``powerlaw`` (genuinely degree-skewed rows with hubs, the hybrid
-path's target; it also persists a ``best_tpu_model`` row pinning the cost
-model's TPU-posture ranking). Rows persist to ``BENCH_formats.json``; each
-geometry also emits
-a ``best=`` row whose ``ratio=`` (t_ref / t_best, ≥ 1.0 by construction
+path's target). Rows persist to ``BENCH_formats.json``; each geometry also
+emits a ``best=`` row whose ``ratio=`` (t_ref / t_best, ≥ 1.0 by construction
 since ref is a candidate) opts into the CI bench-JSON gate
 (`benchmarks/check_bench_json.py`) as a harness-integrity check, and an
 informational ``batched_vs_loop`` row (batched scatter vs sequential
@@ -112,36 +110,7 @@ def sweep_geometry(name: str, batch, dim, nnz, n_b, *, iters: int = 10):
         coo, b, warmup=2, iters=iters)
     row(f"formats/{name}/batched_vs_loop", t_loop * 1e6,
         f"loop_vs_ref={t_loop / t_ref:.2f}x")
-    if name.startswith("powerlaw"):
-        modeled_tpu_row(name, batch=coo.row_ids.shape[0], m_pad=m_pad,
-                        nnz_pad=coo.nnz_pad, n_b=n_b, max_deg=k_pad)
     return times
-
-
-def modeled_tpu_row(name, *, batch, m_pad, nnz_pad, n_b, max_deg):
-    """Persist the cost model's TPU ranking for a skewed geometry.
-
-    The hybrid split exists for the TPU serialization bound, which CPU
-    wall-clock cannot witness (Pallas runs interpret-mode here). This row
-    pins the MODELED decision instead: with the measured max row degree on
-    the Workload, pallas_hybrid must out-rank every prior sparse class
-    (ratio = prior_best_sparse / hybrid, gated >= 1.0 in
-    check_bench_json.py). A cost-model regression that stops picking the
-    hybrid path on its target regime fails the bench gate, not just a
-    unit test.
-    """
-    from repro.autotune.cost_model import Workload, rank
-    from repro.autotune.selector import KINDS
-
-    w = Workload(batch=batch, m_pad=m_pad, nnz_pad=nnz_pad, k_pad=None,
-                 n_b=n_b, max_deg=max_deg)
-    scores = rank(w, allow_pallas=True)
-    sparse = [(impl, t) for impl, t in scores
-              if KINDS[impl] in ("scatter", "ell", "csr", "coo", "hybrid")]
-    best, t_best = sparse[0]
-    t_prior = next(t for impl, t in sparse if KINDS[impl] != "hybrid")
-    row(f"formats/{name}/best_tpu_model", t_best * 1e6,
-        f"best={best},ratio={t_prior / t_best:.2f},modeled md{max_deg}")
 
 
 def main(smoke: bool = False):

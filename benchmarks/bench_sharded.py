@@ -43,7 +43,8 @@ if n_dev == 1:
     fn = jax.jit(lambda v, bb: batched_spmm(a.with_values(v), bb, impl=impl))
     chosen = resolve_impl(a, b, impl=impl).impl
 else:
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((n_dev,), ("data",))
     fn = jax.jit(lambda v, bb: sharded_batched_spmm(
         a.with_values(v), bb, mesh=mesh, impl=impl))
     chosen = resolve_sharded_impl(a, b, mesh, impl=impl).impl

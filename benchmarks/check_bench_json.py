@@ -26,11 +26,8 @@ Two classes of failure, both cheap to hit when a harness regresses silently:
 
 4. **Formats gates** (``BENCH_formats.json`` only, suite="formats"): every
    geometry must carry a measured ``hybrid`` row (the degree-binned dispatch
-   stays in the sweep), the degree-skewed ``powerlaw`` geometry must be
-   present, and its ``formats/powerlaw/best_tpu_model`` row must name
-   ``best=pallas_hybrid`` with ``ratio>=1.0`` — the ISSUE 8 acceptance pin
-   that the cost model keeps picking the hybrid path over the prior best
-   sparse class on its target regime.
+   stays in the sweep), and the degree-skewed ``powerlaw`` geometry must be
+   present.
 
 5. **g-SpMM gates** (``BENCH_gspmm.json`` only, suite="gspmm"): every
    ``maxerr=`` row must stay within the f32 ceiling (all g-SpMM impls are
@@ -76,11 +73,6 @@ SUMMARY_RE = re.compile(
 MIN_BEST_SPEEDUP = 1.0
 
 # --- formats-suite gates (BENCH_formats.json, suite="formats") ------------
-HYBRID_MODEL_ROW = "formats/powerlaw/best_tpu_model"
-BEST_RE = re.compile(r"(?:^|[ ,;])best=(\w+)")
-MIN_HYBRID_RATIO = 1.0
-
-
 def _check_formats_rows(path, rows) -> list[str]:
     errors: list[str] = []
     names = {r.get("name") for r in rows}
@@ -95,27 +87,6 @@ def _check_formats_rows(path, rows) -> list[str]:
         errors.append(
             f"{path.name}: no powerlaw geometry — the degree-skewed family "
             "the hybrid path targets is no longer benchmarked")
-        return errors
-    brow = next((r for r in rows if r.get("name") == HYBRID_MODEL_ROW), None)
-    if brow is None:
-        errors.append(
-            f"{path.name}: missing required row {HYBRID_MODEL_ROW!r}")
-        return errors
-    derived = str(brow.get("derived", ""))
-    bm = BEST_RE.search(derived)
-    rm = RATIO_RE.search(derived)
-    if bm is None or bm.group(1) != "pallas_hybrid":
-        errors.append(
-            f"{path.name}: {HYBRID_MODEL_ROW} best="
-            f"{bm.group(1) if bm else '<missing>'} != pallas_hybrid — the "
-            "cost model stopped picking the hybrid path on the skewed "
-            "regime (ISSUE 8 gate)")
-    if rm is None or float(rm.group(1)) < MIN_HYBRID_RATIO:
-        errors.append(
-            f"{path.name}: {HYBRID_MODEL_ROW} ratio="
-            f"{rm.group(1) if rm else '<missing>'} < {MIN_HYBRID_RATIO} — "
-            "hybrid no longer beats the prior best sparse impl "
-            "(ISSUE 8 gate)")
     return errors
 
 

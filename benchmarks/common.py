@@ -64,9 +64,12 @@ def write_bench_json(suite: str, *, start: int = 0,
     from repro.observability import sanitize_json
 
     path = path or REPO_ROOT / f"BENCH_{suite}.json"
+    dev = jax.devices()[0]
     payload = {
         "suite": suite,
         "backend": jax.default_backend(),
+        "device": {"platform": dev.platform, "device_kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "rows": RESULTS[start:],
     }
     if extra:

@@ -87,7 +87,9 @@ def main() -> None:
                          "export the span buffer as Chrome-trace JSON — "
                          "loads in Perfetto / chrome://tracing")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.trace:
         from repro.observability import set_enabled
 

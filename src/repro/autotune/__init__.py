@@ -20,6 +20,7 @@ from repro.autotune.cache import (  # noqa: F401
 from repro.autotune.cost_model import (  # noqa: F401
     GSPMM_IMPLS,
     PRECISION_IMPLS,
+    TPU_REFUSED,
     Workload,
     estimate,
     estimate_layer,
@@ -28,6 +29,7 @@ from repro.autotune.cost_model import (  # noqa: F401
     rank_layer,
     spmm_plan,
     supports_gspmm,
+    tpu_refusal,
 )
 from repro.autotune.selector import (  # noqa: F401
     KINDS,

@@ -145,6 +145,7 @@ def measure_workload(
     warmup: int = 1,
     iters: int = 5,
     seed: int = 0,
+    calls: int = 1,
 ) -> dict[str, float]:
     """Time each candidate impl on synthetic inputs matching ``w`` EXACTLY.
 
@@ -158,8 +159,15 @@ def measure_workload(
     ``graph_conv_batched`` call per candidate (fused megakernel or stacked
     fallback, matmul + SpMM + channel sum included), never a bare SpMM —
     otherwise the record would override the layer model with a timing of a
-    different computation. Imports are local to avoid a cycle with
-    ``kernels/ops.py`` (which imports this package for ``impl="auto"``).
+    different computation. A candidate that fails to compile or run raises
+    ``RuntimeError`` naming it.
+
+    Each of the ``iters`` timings is the wall time of ``calls`` back-to-back
+    calls divided by ``calls``, blocking once at the end: ``calls=1`` times
+    one call with its dispatch and sync, a larger ``calls`` lets dispatch
+    overlap the device the way a training loop does. Imports are local to
+    avoid a cycle with ``kernels/ops.py`` (which imports this package for
+    ``impl="auto"``).
     """
     import time
 
@@ -246,14 +254,21 @@ def measure_workload(
         try:
             for _ in range(warmup):
                 jax.block_until_ready(fn(*args))
-            ts = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(*args))
-                ts.append(time.perf_counter() - t0)
-            times[impl] = float(np.median(ts))
-        except Exception:  # noqa: BLE001 — an impl a backend can't run is
-            continue       # simply absent from the record
+        except Exception as e:
+            # a candidate that cannot compile or run is a bug in the ladder
+            # (or a kernel the backend refuses): fail loudly, never let it
+            # vanish from the record
+            raise RuntimeError(
+                f"workload {w.key()}: candidate {impl!r} failed to compile "
+                f"or run: {type(e).__name__}: {e}") from e
+        ts = []
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)     # calls run in order on a device
+            ts.append((time.perf_counter() - t0) / calls)
+        times[impl] = float(np.median(ts))
     return times
 
 

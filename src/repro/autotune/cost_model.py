@@ -38,8 +38,10 @@ the execution structure being modeled):
                to no impl: every non-COO path converts inside ``_forward``,
                so the ranking compares like with like; the real conversion
                cost is measured by ``benchmarks/bench_formats.py``.
-- ``pallas_coo``  the one-hot MXU scatter: each CHUNK of non-zeros costs a
-               (CHUNK × m_pad)ᵀ × (CHUNK × n_block) contraction.
+- ``pallas_coo``  one-hot MXU gather and scatter: each CHUNK of non-zeros
+               costs two (CHUNK × m_pad × n_block) contractions at HIGHEST
+               precision (``HIGHEST_PASSES``) and reads int32 ids and f32
+               values (``PANEL_SLOT_BYTES``) under every storage policy.
 - ``dense`` / ``pallas_gemm``  densify (write + read m_pad² per matrix) then
                a batched GEMM at MXU tile efficiency.
 - ``loop``     the non-batched baseline: ``batch`` sequential steps, each
@@ -93,6 +95,37 @@ def supports_gspmm(impl: str) -> bool:
     return base in GSPMM_IMPLS and policy == "f32"
 
 
+# Pallas kernels Mosaic refuses to compile for the TPU (the v5e compile
+# rehearsal in tests/test_tpu_compile.py), by base impl. They stay in the
+# registry because interpret mode runs them as CPU-side references, but
+# they never enter the compiled (``allow_pallas``) ladder, and a compiled
+# call that names one raises (``kernels/ops.py``) instead of failing deep
+# inside Mosaic. Precision variants inherit their base's refusal.
+TPU_REFUSED = {
+    "pallas_ell": "its slot loop gathers B rows in-kernel with jnp.take by "
+                  "an index vector, which Mosaic does not lower",
+    "pallas_csr": "it gathers column ids and B rows in-kernel with jnp.take "
+                  "at flat CSR offsets, which Mosaic does not lower",
+    "pallas_hybrid": "its sparse remainder is the pallas_csr gather loop, "
+                     "and its (1, n_bins) SMEM bound block is not tile-"
+                     "aligned",
+}
+
+
+def tpu_refusal(impl: str, reduce: str = "sum") -> str | None:
+    """Why ``impl`` cannot compile for the TPU (None when it can).
+
+    Besides :data:`TPU_REFUSED`, the COO kernel's ``max`` reduce is refused:
+    its 3-D one-hot select does not lower."""
+    base = precision_of(impl)[0]
+    if base in TPU_REFUSED:
+        return TPU_REFUSED[base]
+    if base == "pallas_coo" and reduce == "max":
+        return ("its max reduce folds a 3-D one-hot select, which Mosaic "
+                "does not lower")
+    return None
+
+
 def _traffic(policy: str, itemsize: int) -> tuple[int, int, int, int]:
     """(value, index, feature, output) bytes-per-element under a storage
     policy. f32 keeps the legacy accounting (4-byte indices, caller
@@ -106,9 +139,9 @@ def _traffic(policy: str, itemsize: int) -> tuple[int, int, int, int]:
 
 # These imports sit BELOW the variant registry on purpose: repro.core's
 # package __init__ pulls in kernels/ops.py, which imports PRECISION_IMPLS /
-# precision_of from this module at import time. Keeping the registry above
-# the repro.core import makes that re-entrant import find the names bound
-# even while this module is still initializing.
+# precision_of / tpu_refusal from this module at import time. Keeping the
+# registry above the repro.core import makes that re-entrant import find the
+# names bound even while this module is still initializing.
 from repro.analysis.roofline import HW  # noqa: E402
 from repro.core.batching import (  # noqa: E402
     CHUNK,
@@ -128,6 +161,14 @@ SCAN_STEP_OVERHEAD = 2e-6  # one sequential scan iteration (the 'loop' path)
 GRID_STEP_OVERHEAD = 0.2e-6  # one Pallas grid step
 SCATTER_PENALTY = 3.0    # read-modify-write amplification of scatter-adds
 _COO_CHUNK = CHUNK       # the COO/fused kernels' non-zero chunk (batching.py)
+# The COO and fused kernels run every MXU product (the one-hot gather and
+# scatter, the fused X·W and the hybrid inverse permutation) at HIGHEST
+# precision, which costs about six bf16 passes of an f32 operand.
+HIGHEST_PASSES = 6.0
+# Bytes per non-zero slot those kernels read: int32 row and column ids and
+# an f32 value under every storage policy (Mosaic cannot load a packed
+# 16-bit chunk-row at a dynamic offset, so the bf16 variants widen them).
+PANEL_SLOT_BYTES = 4 + 4 + 4
 
 
 def _mxu_eff(m: int, n: int) -> float:
@@ -234,7 +275,8 @@ def spmm_plan(w: Workload, impl: str | None = None) -> BatchPlan:
     accounting as kernels/ops.py: ``k_pad`` slots for the ELL kernel,
     ``nnz_pad`` (COO) slots for everything else. ``impl=None`` means
     "best available" (ELL accounting when k_pad is known). The case-3
-    boundary depends only on m_pad, so it is identical either way.
+    boundary depends only on m_pad, so it is identical either way, except
+    for the COO kernel, whose one-hot tiles must also fit the scoped VMEM.
     Precision variants plan as their base impl; the bf16 policy blocks at
     2-byte elements (the features are cast too), the i8 policy keeps the
     caller itemsize (B and the output stay f32)."""
@@ -245,7 +287,8 @@ def spmm_plan(w: Workload, impl: str | None = None) -> BatchPlan:
         slots = w.nnz_pad
     itemsize = 2 if policy == "bf16" else w.itemsize
     return plan_batched_spmm(batch=w.batch, m_pad=w.m_pad, n_b=w.n_b,
-                             slots=slots, itemsize=itemsize)
+                             slots=slots, itemsize=itemsize,
+                             onehot=base == "pallas_coo")
 
 
 def _roofline(flops: float, bytes_: float, unit_peak: float,
@@ -415,67 +458,76 @@ def estimate(w: Workload, impl: str, hw: HW = HW()) -> float:
         if plan.case == 3:
             return float("inf")
         chunks = -(-w.nnz_pad // _COO_CHUNK)
-        # one-hot scatter: a CHUNK×m_pad ᵀ× CHUNK×n_block MXU contraction per
-        # (chunk × matrix × panel)
-        flops = (2.0 * w.batch * plan.p * chunks * _COO_CHUNK
+        # per (chunk × matrix × panel): the one-hot gather onehot(cid)·B and
+        # the one-hot scatter Pᵀ·G, each a CHUNK×m_pad×n_block contraction
+        flops = (2.0 * 2.0 * w.batch * plan.p * chunks * _COO_CHUNK
                  * w.m_pad * plan.n_block)
         per_step = (w.m_pad * plan.n_block * fb
-                    + chunks * _COO_CHUNK
-                    * ((8 + w.itemsize) if f32_path else (2 * ib + vb)))
+                    + chunks * _COO_CHUNK * PANEL_SLOT_BYTES)
         bytes_ = (w.batch * plan.p * per_step + out_bytes
                   + w.batch * w.nnz_pad * d_x * vb + gfix)
         steps = w.batch * plan.p
         eff = _mxu_eff(w.m_pad, plan.n_block)
-        return (_roofline(flops, bytes_, hw.peak_flops * eff, hw)
+        return (_roofline(flops, bytes_, hw.peak_flops * eff / HIGHEST_PASSES,
+                          hw)
                 + steps * GRID_STEP_OVERHEAD + OP_OVERHEAD)
 
     if base in ("fused", "fused_hybrid"):
         # Fused graph-conv megakernel (DESIGN.md §7): per (matrix × panel)
-        # grid step, `channels` MXU feature transforms + one-hot-scatter
-        # SpMMs accumulate into one VMEM panel; intermediates never touch
-        # HBM and the nnz loop is skew-aware (mean chunks, not padded max).
+        # grid step, `channels` MXU feature transforms + one-hot gather and
+        # scatter SpMMs accumulate into one VMEM panel; intermediates never
+        # touch HBM and the nnz loop is skew-aware (mean chunks, not padded
+        # max). Every product runs at HIGHEST precision.
         if w.channels is None or w.n_in is None:
             return float("inf")   # not a layer workload — fused can't run
+        itemsize = 2 if policy == "bf16" else w.itemsize
         plan = plan_fused_graph_conv(
             batch=w.batch, m_pad=w.m_pad, n_in=w.n_in, n_out=w.n_b,
-            channels=w.channels, nnz_pad=w.nnz_pad,
-            itemsize=2 if policy == "bf16" else w.itemsize)
+            channels=w.channels, nnz_pad=w.nnz_pad, itemsize=itemsize,
+            hybrid=base == "fused_hybrid")
         if plan.case == 3:
             return float("inf")
         nnz_eff = w.nnz_avg if w.nnz_avg is not None else w.nnz_pad
+        steps = w.batch * plan.p
         extra = 0.0
         if base == "fused_hybrid":
             # hybrid fold-in (DESIGN.md §12): hub rows leave the one-hot
             # chunk loop for a per-channel dense slab dot; the split pays
-            # the one-time permutation + slab densify. Only a measured
-            # ``max_deg`` past the hub threshold shrinks the chunk count,
-            # so without skew metadata fused_hybrid prices >= fused and
-            # ``auto`` keeps the plain megakernel.
+            # the one-time permutation + slab densify, and the kernel's
+            # epilogue an (m_pad × m_pad) inverse-permutation product. Only
+            # a measured ``max_deg`` past the hub threshold shrinks the
+            # chunk count, so without skew metadata fused_hybrid prices
+            # >= fused and ``auto`` keeps the plain megakernel.
             hp = plan_hybrid(batch=w.batch, m_pad=w.m_pad, n_b=w.n_b,
                              nnz_pad=w.channels * w.nnz_pad,
-                             itemsize=2 if policy == "bf16" else w.itemsize)
+                             itemsize=itemsize)
             md = w.max_deg if w.max_deg is not None else 0
             if md >= hp.dmin:
                 nnz_eff = max(0, nnz_eff - (-(-md // w.channels)))
-            flops_d = (2.0 * w.batch * plan.p * w.channels * hp.d_pad
+            flops_d = (2.0 * steps * w.channels * hp.d_pad
                        * w.m_pad * plan.n_block)
+            flops_perm = 2.0 * steps * w.m_pad * w.m_pad * plan.n_block
             slab_bytes = 2.0 * w.batch * w.channels * hp.d_pad * w.m_pad * vb
             perm_bytes = 6.0 * w.batch * w.m_pad * 4
-            extra = (flops_d / (hw.peak_flops
-                                * _mxu_eff(max(hp.d_pad, 1), plan.n_block))
-                     + (slab_bytes + perm_bytes) / hw.hbm_bw
-                     + 5 * OP_OVERHEAD)
+            extra = (HIGHEST_PASSES * (
+                flops_d / (hw.peak_flops
+                           * _mxu_eff(max(hp.d_pad, 1), plan.n_block))
+                + flops_perm / (hw.peak_flops
+                                * _mxu_eff(w.m_pad, plan.n_block)))
+                + (slab_bytes + perm_bytes) / hw.hbm_bw
+                + 5 * OP_OVERHEAD)
         chunks = max(1, -(-nnz_eff // _COO_CHUNK))
-        steps = w.batch * plan.p
+        # per channel: the X·W transform, then per chunk the one-hot gather
+        # and the one-hot scatter (two CHUNK×m_pad×n_block contractions)
         flops = (2.0 * steps * w.channels * w.m_pad * plan.n_block
-                 * (w.n_in + chunks * _COO_CHUNK))
+                 * (w.n_in + 2 * chunks * _COO_CHUNK))
         per_step = (w.m_pad * w.n_in * fb                           # X panel
                     + w.channels * w.n_in * plan.n_block * fb       # W
-                    + w.channels * chunks * _COO_CHUNK
-                    * ((8 + w.itemsize) if f32_path else (2 * ib + vb)))
+                    + w.channels * chunks * _COO_CHUNK * PANEL_SLOT_BYTES)
         bytes_ = steps * per_step + out_bytes       # output written ONCE
         eff = _mxu_eff(w.m_pad, plan.n_block)
-        return (_roofline(flops, bytes_, hw.peak_flops * eff, hw)
+        return (_roofline(flops, bytes_, hw.peak_flops * eff / HIGHEST_PASSES,
+                          hw)
                 + steps * GRID_STEP_OVERHEAD + OP_OVERHEAD + extra)
 
     if impl in ("dense", "pallas_gemm"):
@@ -497,18 +549,20 @@ def _candidates(dtype: str, allow_pallas: bool) -> list[str]:
     """The SpMM candidate ladder for a precision policy. ``dtype="f32"``
     reproduces the legacy candidate set exactly; reduced policies ADD their
     variants next to the full-precision impls (the model decides whether the
-    byte savings beat f32, it is never forced)."""
+    byte savings beat f32, it is never forced).
+
+    ``allow_pallas`` is the compiled TPU posture, so it admits only the
+    Pallas kernels that compile there: ``pallas_ell``, ``pallas_csr``,
+    ``pallas_hybrid`` and their bf16/i8 variants are left out for the
+    reasons in :data:`TPU_REFUSED` (which also leaves the i8 policy with no
+    Pallas variant of its own)."""
     cands = ["ref", "ell", "csr", "hybrid", "dense", "loop"]
     if dtype in ("bf16", "i8"):
         cands += ["ell_bf16", "csr_bf16"]
     if allow_pallas:
-        cands += ["pallas_ell", "pallas_csr", "pallas_coo", "pallas_hybrid",
-                  "pallas_gemm"]
+        cands += ["pallas_coo", "pallas_gemm"]
         if dtype in ("bf16", "i8"):
-            cands += ["pallas_ell_bf16", "pallas_csr_bf16", "pallas_coo_bf16",
-                      "pallas_hybrid_bf16"]
-        if dtype == "i8":
-            cands += ["pallas_ell_i8", "pallas_csr_i8"]
+            cands += ["pallas_coo_bf16"]
     return cands
 
 
@@ -526,8 +580,10 @@ def rank(w: Workload, *, allow_pallas: bool = True,
     if w.is_gspmm:
         # op × reduce × edge-feature workloads only admit the g-SpMM-capable
         # impls (DESIGN.md §11): the GEMM class IS the (mul, sum) product,
-        # and the precision variants are (mul, sum)-only.
-        cands = [c for c in cands if supports_gspmm(c)]
+        # and the precision variants are (mul, sum)-only. A compiled ladder
+        # also drops what the TPU refuses for this reduce (pallas_coo's max)
+        cands = [c for c in cands if supports_gspmm(c)
+                 and not (allow_pallas and tpu_refusal(c, w.reduce))]
     scored = [(i, estimate(w, i, hw)) for i in cands]
     scored = [(i, t) for i, t in scored if t != float("inf")]
     return tuple(sorted(scored, key=lambda it: it[1]))

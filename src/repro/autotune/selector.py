@@ -47,7 +47,8 @@ def _layer_plan(w: Workload, impl: str) -> BatchPlan:
         return plan_fused_graph_conv(
             batch=w.batch, m_pad=w.m_pad, n_in=w.n_in or 0, n_out=w.n_b,
             channels=w.channels or 1, nnz_pad=w.nnz_pad,
-            itemsize=2 if policy == "bf16" else w.itemsize)
+            itemsize=2 if policy == "bf16" else w.itemsize,
+            hybrid=base == "fused_hybrid")
     return spmm_plan(dataclasses.replace(
         w, batch=w.batch * (w.channels or 1), channels=None, n_in=None,
         nnz_avg=None), impl)

@@ -32,10 +32,38 @@ LANES = 128                         # vector lane width (last dim tiling)
 SUBLANES = 8                        # second-to-last dim tiling (f32)
 LARGE_M = 8192                      # paper's case-3 threshold, kept verbatim
 CHUNK = 128                         # COO non-zero chunk (one MXU sublane tile)
+# share of VMEM_BYTES (the scoped limit) that a one-hot kernel's estimated
+# step may take: Mosaic's own scratch varies with the shape (the v5e
+# rehearsal measured a fused step at 16.2 MiB for m_pad 3712 but 18.5 MiB
+# for the smaller 3328), so a quarter is kept free
+ONEHOT_VMEM_LIMIT = 3 * VMEM_BYTES // 4
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """VMEM bytes of a ``(rows, cols)`` block: Mosaic pads the last dim to
+    ``LANES`` and the second-to-last to ``SUBLANES``."""
+    return _round_up(max(rows, 1), SUBLANES) * _round_up(max(cols, 1),
+                                                         LANES) * itemsize
+
+
+def onehot_kernel_vmem(*, m_pad: int, n_block: int, block_bytes: int,
+                       temps: int) -> int:
+    """Scoped VMEM that one grid step of a one-hot gather/scatter kernel
+    (the COO and fused kernels) needs on the TPU: its blocks double-buffered
+    (``block_bytes`` is one copy, lane-padded — :func:`tile_bytes`),
+    ``temps`` f32 ``(m_pad, n_block)`` temporaries, and the gather and
+    scatter one-hot tiles. Checked against the v5e compile rehearsal: a fused
+    step (4 channels, 62→64, three temporaries: accumulator, ``U_ch`` and
+    the product) at m_pad 3712 is estimated at 17.3 MiB where Mosaic needs
+    16.2 MiB, at 4096 at 19.0 MiB where it needs 17.9 MiB; the COO kernel
+    compiles at m_pad 4608 (estimate 16.0 MiB) and not at 8192. Plans whose
+    estimate exceeds :data:`ONEHOT_VMEM_LIMIT` are case 3."""
+    return (2 * block_bytes + temps * tile_bytes(m_pad, n_block)
+            + 2 * tile_bytes(CHUNK, m_pad))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +108,7 @@ def plan_batched_spmm(
     n_b: int,
     slots: int,
     itemsize: int = 4,
+    onehot: bool = False,
 ) -> BatchPlan:
     """Size the column panels the way the paper sizes cache blocks.
 
@@ -89,6 +118,11 @@ def plan_batched_spmm(
         out panel   m_pad * n_block * itemsize
         B panel     m_pad * n_block * itemsize   (same rows, same panel)
         indices     ~ 2 * slots_bytes
+
+    ``onehot=True`` plans the COO kernel (one-hot gather and scatter), whose
+    step must also fit the scoped VMEM (:func:`onehot_kernel_vmem` within
+    :data:`ONEHOT_VMEM_LIMIT`); a matrix too large for that even at one
+    128-lane panel is case 3.
     """
     m_pad = _round_up(max(m_pad, 1), SUBLANES)
     if m_pad > LARGE_M:
@@ -97,15 +131,27 @@ def plan_batched_spmm(
         return BatchPlan(batch, m_pad, n_b, n_b, 1, 3, 0)
 
     idx_bytes = 2 * slots * 8  # int32 ids + values, per matrix
+
+    def fits(nb: int) -> bool:
+        if not onehot:
+            return True
+        panels = 3 * tile_bytes(-(-slots // CHUNK), CHUNK)   # ids, values
+        return onehot_kernel_vmem(
+            m_pad=m_pad, n_block=nb, temps=1,
+            block_bytes=panels + 2 * tile_bytes(m_pad, nb, itemsize),
+        ) <= ONEHOT_VMEM_LIMIT
+
     n_block = _round_up(n_b, LANES) if n_b >= LANES else n_b
     while n_block > LANES:
         step = 2 * m_pad * n_block * itemsize + idx_bytes
-        if step <= VMEM_TILE_BUDGET:
+        if step <= VMEM_TILE_BUDGET and fits(n_block):
             break
         # halve along 128-lane multiples, mirroring the paper's "divide the
         # output along the column" (Fig. 5-(b)/(d))
         n_block = _round_up(n_block // 2, LANES)
     step = 2 * m_pad * n_block * itemsize + idx_bytes
+    if not fits(n_block):
+        return BatchPlan(batch, m_pad, n_b, n_b, 1, 3, step)
     p = -(-n_b // n_block)
     case = 1 if p == 1 else 2
     return BatchPlan(batch, m_pad, n_b, n_block, p, case, step)
@@ -213,19 +259,30 @@ def plan_fused_graph_conv(
     nnz_pad: int,
     itemsize: int = 4,
     nnz_per_sample=None,
+    hybrid: bool = False,
 ) -> BatchPlan:
     """Blocking plan for the fused graph-conv megakernel (DESIGN.md §7).
 
     One grid step computes, for one (matrix × output-column panel), the whole
     layer: ``channels`` MXU products ``X·W_ch + b_ch`` immediately consumed by
-    the one-hot-scatter SpMM, accumulated into a single VMEM-resident panel.
-    The per-step working set is therefore:
+    the one-hot gather and scatter SpMM, accumulated into a single
+    VMEM-resident panel. The per-step working set is therefore:
 
         X panel      m_pad * n_in * itemsize
         W panel      channels * n_in * n_block * itemsize
         bias panel   channels * n_block * itemsize
-        indices      channels * nnz_chunks * CHUNK * (8 + itemsize)
+        indices      channels * nnz_chunks * CHUNK * 12   (int32 ids, f32 values)
         acc/out      2 * m_pad * n_block * 4       (f32 accumulator + store)
+
+    Panels are halved until that fits ``VMEM_TILE_BUDGET`` and the step's
+    scoped VMEM (:func:`onehot_kernel_vmem`: double-buffered lane-padded
+    blocks, the accumulator, ``U_ch`` and their product, the one-hot tiles)
+    fits :data:`ONEHOT_VMEM_LIMIT`. ``hybrid=True`` plans the hybrid fold-in
+    (``fused_hybrid``), whose step also holds its hub slabs (``d_pad`` from
+    :func:`plan_hybrid` over the layer's whole edge budget), the rank row
+    and the ``(m_pad, m_pad)`` f32 inverse-permutation tile. A step that
+    does not fit even at one 128-lane panel is case 3: the megakernel is
+    not offered.
 
     ``nnz_per_sample`` (host-side: per-sample totals, or per-(sample ×
     channel) rows for the exact sum-of-ceils — see :func:`chunk_counts`)
@@ -242,8 +299,21 @@ def plan_fused_graph_conv(
         return BatchPlan(batch, m_pad, n_out, n_out, 1, 3, 0, sample_chunks)
 
     chunks_pad = max(1, -(-nnz_pad // CHUNK))
-    idx_bytes = channels * chunks_pad * CHUNK * (8 + itemsize)
+    idx_bytes = channels * chunks_pad * CHUNK * 12
     x_bytes = m_pad * n_in * itemsize
+    # scoped VMEM beyond the panels: id/value panels, X, and for the hybrid
+    # fold-in the hub slabs, the rank row and the inverse permutation
+    fixed_blocks = (3 * channels * tile_bytes(chunks_pad, CHUNK)
+                    + tile_bytes(m_pad, n_in, itemsize))
+    fixed_temps = 0
+    if hybrid:
+        d_pad = plan_hybrid(batch=batch, m_pad=m_pad, n_b=n_out,
+                            nnz_pad=channels * nnz_pad,
+                            itemsize=itemsize).d_pad
+        if d_pad:
+            fixed_blocks += (channels * tile_bytes(d_pad, m_pad, itemsize)
+                             + tile_bytes(1, m_pad))
+            fixed_temps = tile_bytes(m_pad, m_pad)
     n_block = _round_up(n_out, LANES) if n_out >= LANES else n_out
 
     def step_bytes(nb: int) -> int:
@@ -251,10 +321,23 @@ def plan_fused_graph_conv(
                 + channels * nb * itemsize + idx_bytes
                 + 2 * m_pad * nb * 4)
 
-    while n_block > LANES and step_bytes(n_block) > VMEM_TILE_BUDGET:
+    def fits(nb: int) -> bool:
+        blocks = (fixed_blocks + channels * tile_bytes(n_in, nb, itemsize)
+                  + tile_bytes(channels, nb, itemsize)
+                  + tile_bytes(m_pad, nb, itemsize))           # + out panel
+        return onehot_kernel_vmem(m_pad=m_pad, n_block=nb, temps=3,
+                                  block_bytes=blocks) \
+            + fixed_temps <= ONEHOT_VMEM_LIMIT
+
+    while n_block > LANES and (step_bytes(n_block) > VMEM_TILE_BUDGET
+                               or not fits(n_block)):
         # halve along 128-lane multiples — the paper's "divide the output
         # along the column" (Fig. 5-(b)/(d)) applied to the fused epilogue
         n_block = _round_up(n_block // 2, LANES)
+    if not fits(n_block):
+        # even the narrowest panel overflows the scoped VMEM: like case 3
+        return BatchPlan(batch, m_pad, n_out, n_out, 1, 3,
+                         step_bytes(n_block), sample_chunks)
     p = -(-n_out // n_block)
     case = 1 if p == 1 else 2
     return BatchPlan(batch, m_pad, n_out, n_block, p, case,

@@ -25,7 +25,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.formats import BatchedCOO, narrow_col_ids
+from repro.core.formats import BatchedCOO
 from repro.core.spmm import batched_spmm
 from repro.kernels import resolve_interpret
 from repro.kernels.ref import spmm_coo_single
@@ -137,9 +137,9 @@ def graph_conv_batched(
     ``precision`` ("f32"|"bf16"|"i8") is the layer's dtype policy under
     ``impl="auto"`` (DESIGN.md §10); pinning a variant impl (e.g.
     ``"fused_bf16"``) applies its policy directly. The bf16 megakernel
-    variant casts values/X/W/bias to bfloat16 and narrows the index storage
-    to int16 before dispatch; the f32 accumulator lives in the kernel and
-    the output is cast back to X's dtype.
+    variant casts values/X/W/bias to bfloat16 before dispatch; the f32
+    accumulator lives in the kernel and the output is cast back to X's
+    dtype.
 
     ``mesh=`` shards the batch axis over the mesh's ``"data"`` axis
     (DESIGN.md §6): the fused megakernel dispatches per shard via
@@ -163,9 +163,7 @@ def graph_conv_batched(
         rids, cids, vals, nnz = stack_channels(adj)
         xx, ww, bb = x, params["w"], params["b"]
         if policy == "bf16":
-            m_pad = x.shape[1]
-            rids = narrow_col_ids(rids, m_pad)
-            cids = narrow_col_ids(cids, m_pad)
+            # ids stay int32: the megakernel reads them as int32 chunk-rows
             vals = vals.astype(jnp.bfloat16)
             xx = xx.astype(jnp.bfloat16)
             ww = ww.astype(jnp.bfloat16)

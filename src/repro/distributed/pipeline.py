@@ -24,7 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -74,7 +74,7 @@ def build_pp_loss(cfg: ModelConfig, mesh, *, microbatches: int,
             shard_map, mesh=mesh,
             in_specs=({"blocks": blocks_spec, **other_spec}, P()),
             out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         def run(params, mbs):
             stage = jax.lax.axis_index(pipe_axis)
             blocks_local = jax.tree.map(lambda x: x, params["blocks"])

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.formats import BatchedCOO
@@ -165,7 +165,7 @@ def sharded_batched_spmm(
 
     fwd_sharded = shard_map(
         _fwd_local, mesh=mesh, in_specs=(spec,) * 5, out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def _bwd_local(rids, cids, nz, values, b_local, dc):
         # dB = Aᵀ·dC per shard: COO index swap, or csr_transpose for the
@@ -177,7 +177,7 @@ def sharded_batched_spmm(
 
     bwd_sharded = shard_map(
         _bwd_local, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec, spec),
-        check_rep=False)
+        check_vma=False)
 
     @jax.custom_vjp
     def f(values, bb):
@@ -294,7 +294,7 @@ def sharded_batched_gspmm(
 
     fwd_sharded = shard_map(
         _fwd_local, mesh=mesh, in_specs=(spec,) * 5, out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def _bwd_local(rids, cids, nz, values, b_local, c_local, dc):
         return gspmm_backward(rids, cids, nz, values, b_local, c_local, dc,
@@ -303,7 +303,7 @@ def sharded_batched_gspmm(
 
     bwd_sharded = shard_map(
         _bwd_local, mesh=mesh, in_specs=(spec,) * 7,
-        out_specs=(spec, spec), check_rep=False)
+        out_specs=(spec, spec), check_vma=False)
 
     @jax.custom_vjp
     def f(values, bb):
@@ -381,14 +381,15 @@ def sharded_fused_graph_conv(
 
         row_ids, col_ids, values, nnz, x = map(
             padb, (row_ids, col_ids, values, nnz, x))
+    hybrid = precision_of(impl)[0] == "fused_hybrid"
     plan = plan_fused_graph_conv(
         batch=(batch + pad) // n, m_pad=m_pad, n_in=n_in, n_out=n_out,
-        channels=channels, nnz_pad=nnz_pad, itemsize=x.dtype.itemsize)
+        channels=channels, nnz_pad=nnz_pad, itemsize=x.dtype.itemsize,
+        hybrid=hybrid)
     if plan.case == 3:
         raise ValueError(
-            f"m_pad={plan.m_pad} is planner case 3 (> LARGE_M): use the "
-            "unfused graph_conv_batched fallback")
-    hybrid = precision_of(impl)[0] == "fused_hybrid"
+            f"m_pad={plan.m_pad} is planner case 3 (> LARGE_M, or a working "
+            "set past VMEM): use the unfused graph_conv_batched fallback")
     # 4th sharded forward operand: the hybrid prep re-derives chunk counts
     # AFTER hub extraction, so it needs the raw per-channel nnz; the plain
     # megakernel takes precomputed chunk counts
@@ -415,7 +416,7 @@ def sharded_fused_graph_conv(
 
     fwd_sharded = shard_map(
         _fwd_local, mesh=mesh, in_specs=(spec,) * 5 + (repl, repl),
-        out_specs=spec, check_rep=False)
+        out_specs=spec, check_vma=False)
 
     def _bwd_local(rids_l, cids_l, vals_l, x_l, w_l, b_l, y_l, dy_l):
         dvals, dx, dw, db, _ = fused_bwd(
@@ -428,7 +429,7 @@ def sharded_fused_graph_conv(
     bwd_sharded = shard_map(
         _bwd_local, mesh=mesh,
         in_specs=(spec,) * 4 + (repl, repl) + (spec, spec),
-        out_specs=(spec, spec, repl, repl), check_rep=False)
+        out_specs=(spec, spec, repl, repl), check_vma=False)
 
     @jax.custom_vjp
     def f(vals, xx, ww, bb):

@@ -44,3 +44,25 @@ def default_interpret() -> bool:
 def resolve_interpret(interpret: bool | None) -> bool:
     """``None`` → :func:`default_interpret`; an explicit bool passes through."""
     return default_interpret() if interpret is None else bool(interpret)
+
+
+def require_compiled(interpret: bool | None = None) -> None:
+    """Refuse to run anywhere but compiled on a TPU.
+
+    For runs whose results are only meaningful on the chip (``chip_smoke.py``,
+    measurements): raises ``RuntimeError`` when JAX's backend is not a TPU,
+    and when ``interpret`` — a config's ``interpret`` field, resolved through
+    :func:`resolve_interpret`, so ``$REPRO_INTERPRET`` counts too — would run
+    the Pallas kernels in the interpreter there."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"compiled run needs a TPU backend; JAX found {backend!r}")
+    if resolve_interpret(interpret):
+        source = ("interpret=True" if interpret
+                  else f"REPRO_INTERPRET={os.environ.get('REPRO_INTERPRET')}")
+        raise RuntimeError(
+            f"{source} would run the Pallas kernels in interpret mode on the "
+            "TPU; unset it for a compiled run")

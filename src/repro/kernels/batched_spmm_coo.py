@@ -5,12 +5,15 @@ The GPU version splits work by *non-zero* and resolves output races with
 ``atomicAdd`` on shared memory. TPUs have no atomics; the adaptation
 (DESIGN.md §2, "atomics → one-hot MXU scatter") is:
 
-- non-zeros are processed in CHUNK-sized vector groups;
-- the *gather* side (``B[cid]``) is a sublane-axis ``jnp.take``;
+- non-zeros are processed in CHUNK-sized vector groups, one grid-resident
+  ``(chunks, CHUNK)`` index panel per matrix, read a chunk-row at a time;
+- the *gather* side (``B[cid]``) is a one-hot matrix product
+  ``onehot(cid) @ B`` — a (CHUNK × m_pad) × (m_pad × n_block) contraction on
+  the MXU (Mosaic has no sublane gather by a vector of indices);
 - the *scatter-add* side (``C[rid] += …``) becomes a one-hot matrix product
-  ``P.T @ G`` where ``P[i, r] = (rid[i] == r)`` — a (chunk × m_pad)ᵀ ×
-  (chunk × n_block) contraction that runs on the MXU. Races disappear because
-  the reduction is a dot-product, not a read-modify-write.
+  ``Pᵀ @ G`` where ``Pᵀ[r, i] = (rid[i] == r)`` — an (m_pad × CHUNK) ×
+  (CHUNK × n_block) contraction that also runs on the MXU. Races disappear
+  because the reduction is a dot-product, not a read-modify-write.
 
 Accumulation across chunks happens in a VMEM-resident f32 accumulator — the
 shared-memory-resident output of Fig. 5-(a) — and the column-panel grid
@@ -20,15 +23,22 @@ g-SpMM generalization (DESIGN.md §11): a static ``(op, reduce)`` pair turns
 ``C[rid] += val · B[cid]`` into ``C[rid] = reduce(op(B[cid], e))``. The
 ``(mul, sum)`` corner keeps the unmasked legacy path (padding values 0.0
 are neutral); every other corner takes the per-matrix true non-zero count
-``nnz`` (SMEM scalar) and masks slots ``i ≥ nnz`` explicitly. ``sum`` stays
-a one-hot MXU contraction. ``max`` has no dot-product form — it runs a
-one-hot *select*: each SUB-slot group of the chunk broadcasts its messages
-against its one-hot row mask and folds with ``maximum`` (the (SUB, m_pad,
-n_block) intermediate bounds the VMEM cost of a full-chunk broadcast).
+``nnz`` (scalar-prefetched into SMEM) and masks slots ``i ≥ nnz``
+explicitly. ``sum`` stays a one-hot MXU contraction. ``max`` has no
+dot-product form — it runs a one-hot *select*: each SUB-slot group of the
+chunk broadcasts its messages against its one-hot row mask and folds with
+``maximum`` (the (SUB, m_pad, n_block) intermediate bounds the VMEM cost of
+a full-chunk broadcast).
 ``mean`` and the empty-row identity fix-up of ``max`` are applied by the
 wrapper after the kernel (an XLA degree count — the kernel itself only
 knows sum/max). Edge values may be scalars ``(batch, nnz_pad)`` or feature
 vectors ``(batch, nnz_pad, d_e)`` with ``d_e == n_b``.
+
+Compiled for the TPU, the ``max`` reduce is refused (its 3-D one-hot select
+does not lower), so ``autotune`` keeps ``pallas_coo`` off the ``max``
+ladder there (:data:`repro.autotune.cost_model.TPU_REFUSED`). Ids enter as
+int32 and bf16 values are widened to f32 before the call: Mosaic cannot load
+one packed 16-bit chunk-row at a dynamic offset.
 """
 from __future__ import annotations
 
@@ -46,50 +56,57 @@ NEG_INF = -3.0e38   # finite stand-in for -inf (matches kernels/ref.py)
 # one-hot-select group size for the max reduce: bounds the broadcast
 # intermediate at (SUB, m_pad, n_block) f32 in VMEM per fold
 _MAX_SUB = 8
+HIGHEST = jax.lax.Precision.HIGHEST   # one-hot products stay exact in f32
 
 
 def _kernel(*refs, m_pad: int, chunks: int, has_nnz: bool, op: str,
-            reduce: str):
+            reduce: str, vec: bool):
     refs = list(refs)
     nnz_ref = refs.pop(0) if has_nnz else None
     rid_ref, cid_ref, val_ref, b_ref, c_ref = refs
-    bb = b_ref[0]                                    # (m_pad, n_block)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, m_pad), 1)
+    bb = b_ref[0].astype(jnp.float32)                # (m_pad, n_block)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, m_pad), 1)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (m_pad, CHUNK), 0)
+    n_valid = nnz_ref[pl.program_id(0)] if has_nnz else None
 
     def messages(i):
-        sl = pl.dslice(i * CHUNK, CHUNK)
-        # ids may be narrowed int16 storage (DESIGN.md §10); widen to int32
-        # for the take / iota compare — Mosaic wants 32-bit indices
-        rid = rid_ref[0, sl].astype(jnp.int32)       # (CHUNK,)
-        cid = cid_ref[0, sl].astype(jnp.int32)
-        g = jnp.take(bb, cid, axis=0).astype(jnp.float32)
+        rid = rid_ref[0, pl.ds(i, 1), :]             # (1, CHUNK) int32
+        cid = cid_ref[0, pl.ds(i, 1), :].reshape(CHUNK, 1)
+        # gather B[cid] as a one-hot MXU product
+        g = jax.lax.dot_general(
+            (cid == col_iota).astype(jnp.float32), bb,
+            (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32)      # (CHUNK, n_block)
         if op != "copy_lhs":
-            val = val_ref[0, sl].astype(jnp.float32)  # (CHUNK[, n_block])
-            if val.ndim == 1:
-                val = val[:, None]
+            if vec:
+                val = val_ref[0, pl.ds(i * CHUNK, CHUNK), :]
+            else:
+                val = val_ref[0, pl.ds(i, 1), :].reshape(CHUNK, 1)
+            val = val.astype(jnp.float32)
             g = g * val if op == "mul" else g + val
         valid = None
         if has_nnz:
             # explicit validity: the padding invariant (value 0.0 neutral)
             # only holds for (mul, sum)
-            valid = (i * CHUNK + jax.lax.iota(jnp.int32, CHUNK)) < nnz_ref[0]
+            slot = i * CHUNK + jax.lax.broadcasted_iota(
+                jnp.int32, (CHUNK, 1), 0)
+            valid = slot < n_valid
         return rid, g, valid
 
     def body_sum(i, acc):
         rid, g, valid = messages(i)
         if valid is not None:
-            g = jnp.where(valid[:, None], g, 0.0)
-        p = (rid[:, None] == row_iota).astype(jnp.float32)   # (CHUNK, m_pad)
-        # scatter-add as MXU contraction: acc[r] += Σ_i p[i, r] * g[i]
+            g = jnp.where(valid, g, 0.0)
+        p = (row_iota == rid).astype(jnp.float32)    # (m_pad, CHUNK)
+        # scatter-add as MXU contraction: acc[r] += Σ_i p[r, i] * g[i]
         return acc + jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            p, g, (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32)
 
     def body_max(i, acc):
         rid, g, valid = messages(i)
-        g = jnp.where(valid[:, None], g, NEG_INF)
-        p = rid[:, None] == row_iota                         # (CHUNK, m_pad)
+        g = jnp.where(valid, g, NEG_INF)
+        p = rid.reshape(CHUNK, 1) == col_iota                # (CHUNK, m_pad)
         # one-hot select: no dot-product form for max, so fold SUB slots at
         # a time — candidate[s, r, :] is message s where it targets row r
         for s in range(0, CHUNK, _MAX_SUB):
@@ -146,32 +163,44 @@ def batched_spmm_coo(
         if vec:
             values = jnp.pad(values, ((0, 0), (0, 0), (0, pad)))
 
+    # ids and scalar values as (batch, chunks, CHUNK) panels: the block's
+    # last two dims are the array's, and one chunk is one row
+    def panel(t, dtype):
+        return t.astype(dtype).reshape(batch, chunks, CHUNK)
+
+    rids = panel(row_ids, jnp.int32)
+    cids = panel(col_ids, jnp.int32)
+    if vec:
+        values = values.astype(jnp.float32)
+        val_spec = pl.BlockSpec((1, nnz_pad, n_block),
+                                lambda i, j, *_: (i, 0, j))
+    else:
+        values = panel(values, jnp.float32)
+        val_spec = pl.BlockSpec((1, chunks, CHUNK), lambda i, j, *_: (i, 0, 0))
+
     # the kernel reduces sum or max; mean = sum kernel + XLA degree scale,
     # and max needs the empty-row identity fix-up — both via the true
     # per-row degree, an XLA scatter-add over the (cheap) index arrays
     kernel_reduce = "sum" if reduce == "mean" else reduce
-    val_spec = (
-        pl.BlockSpec((1, nnz_pad, n_block), lambda i, j: (i, 0, j))
-        if vec else pl.BlockSpec((1, nnz_pad), lambda i, j: (i, 0)))
-    in_specs = [
-        pl.BlockSpec((1, nnz_pad), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, nnz_pad), lambda i, j: (i, 0)),
-        val_spec,
-        pl.BlockSpec((1, m_pad, n_block), lambda i, j: (i, 0, j)),
-    ]
-    operands = [row_ids, col_ids, values, b]
+    idx_spec = pl.BlockSpec((1, chunks, CHUNK), lambda i, j, *_: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=int(nnz is not None),
+        grid=(batch, p),
+        in_specs=[idx_spec, idx_spec, val_spec,
+                  pl.BlockSpec((1, m_pad, n_block),
+                               lambda i, j, *_: (i, 0, j))],
+        out_specs=pl.BlockSpec((1, m_pad, n_block),
+                               lambda i, j, *_: (i, 0, j)),
+    )
+    operands = [rids, cids, values, b]
     if nnz is not None:
-        in_specs.insert(0, pl.BlockSpec((1,), lambda i, j: (i,),
-                                        memory_space=pltpu.SMEM))
         operands.insert(0, nnz.astype(jnp.int32))
 
     out = pl.pallas_call(
         functools.partial(_kernel, m_pad=m_pad, chunks=chunks,
                           has_nnz=nnz is not None, op=op,
-                          reduce=kernel_reduce),
-        grid=(batch, p),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, m_pad, n_block), lambda i, j: (i, 0, j)),
+                          reduce=kernel_reduce, vec=vec),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, m_pad, p * n_block), b.dtype),
         interpret=interpret,
     )(*operands)

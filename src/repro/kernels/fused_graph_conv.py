@@ -49,6 +49,7 @@ from repro.core.batching import (
 from repro.kernels import resolve_interpret
 
 EPILOGUES = ("none", "relu")
+HIGHEST = jax.lax.Precision.HIGHEST   # MXU products stay f32-exact
 
 
 def _kernel(chunks_ref, rid_ref, cid_ref, val_ref, x_ref, w_ref, b_ref,
@@ -63,54 +64,55 @@ def _kernel(chunks_ref, rid_ref, cid_ref, val_ref, x_ref, w_ref, b_ref,
     else:
         (c_ref,) = rest
     m_pad = c_ref.shape[1]
+    s = pl.program_id(0)
     xx = x_ref[0].astype(jnp.float32)                     # (m_pad, n_in)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, m_pad), 1)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, m_pad), 1)
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (m_pad, CHUNK), 0)
     acc = jnp.zeros(c_ref.shape[1:], jnp.float32)
     if d_pad:
         dacc = jnp.zeros((d_pad, c_ref.shape[2]), jnp.float32)
 
+    def mm(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+
     for ch in range(channels):    # static unroll; channels is small (bond types)
         # feature transform on the MXU — U_ch never leaves VMEM
-        u = jax.lax.dot_general(
-            xx, w_ref[ch].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) + b_ref[ch].astype(jnp.float32)[None, :]
+        u = mm(xx, w_ref[ch].astype(jnp.float32)) \
+            + b_ref[pl.ds(ch, 1), :].astype(jnp.float32)
 
         if d_pad:
             # hub rows: this channel's pre-gathered dense tiles contract
             # against U_ch on the MXU — no scatter loop for the heavy rows
-            dacc = dacc + jax.lax.dot_general(
-                slab_ref[0, ch].astype(jnp.float32), u,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            dacc = dacc + mm(slab_ref[0, ch].astype(jnp.float32), u)
 
         def body(i, a, u=u, ch=ch):
-            sl = pl.dslice(i * CHUNK, CHUNK)
-            # ids may be narrowed int16 storage (DESIGN.md §10); widen to
-            # int32 for the take / iota compare
-            rid = rid_ref[0, ch, sl].astype(jnp.int32)    # (CHUNK,)
-            cid = cid_ref[0, ch, sl].astype(jnp.int32)
-            val = val_ref[0, ch, sl].astype(jnp.float32)
-            g = jnp.take(u, cid, axis=0) * val[:, None]
-            p1 = (rid[:, None] == row_iota).astype(jnp.float32)
-            # scatter-add as MXU contraction (DESIGN.md §2)
-            return a + jax.lax.dot_general(
-                p1, g, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            # one chunk-row of the (chunks, CHUNK) index panels
+            rid = rid_ref[0, ch, pl.ds(i, 1), :]              # (1, CHUNK)
+            cid = cid_ref[0, ch, pl.ds(i, 1), :].reshape(CHUNK, 1)
+            val = val_ref[0, ch, pl.ds(i, 1), :].reshape(CHUNK, 1)
+            # gather U_ch[cid] and scatter-add into rid, both as one-hot
+            # MXU contractions (DESIGN.md §2)
+            g = mm((cid == col_iota).astype(jnp.float32), u) * val
+            return a + mm((row_iota == rid).astype(jnp.float32), g)
 
         # skew-aware bound: this graph's real chunk count, not the batch max
-        n_ch = jnp.minimum(chunks_ref[0, ch], total_chunks)
+        n_ch = jnp.minimum(chunks_ref[s * channels + ch], total_chunks)
         acc = jax.lax.fori_loop(0, n_ch, body, acc)
 
     if d_pad:
         # accumulator is in SORTED row order (hub rows first); merge the MXU
         # tiles, then the inverse permutation is fused into the epilogue so
-        # outputs leave in the original row order (DESIGN.md §12)
+        # outputs leave in the original row order (DESIGN.md §12):
+        # out[r] = acc[rank[r]], a one-hot (transposed) MXU product
         head = acc[:d_pad] + dacc
         acc = head if d_pad == m_pad else jnp.concatenate([head, acc[d_pad:]])
-        acc = jnp.take(acc, rank_ref[0].astype(jnp.int32), axis=0)
+        perm_t = (jax.lax.broadcasted_iota(jnp.int32, (m_pad, m_pad), 0)
+                  == rank_ref[0]).astype(jnp.float32)     # [s, r]
+        acc = jax.lax.dot_general(perm_t, acc, (((0,), (0,)), ((), ())),
+                                  precision=HIGHEST,
+                                  preferred_element_type=jnp.float32)
 
     if has_residual:
         acc = acc + res_ref[0].astype(jnp.float32)
@@ -142,7 +144,12 @@ def fused_forward(
     ``rank``/``slab`` (set together by :func:`fused_hybrid_forward`) switch on
     the hybrid dispatch: per-channel hub tiles are contracted on the MXU and
     the accumulator — built in degree-sorted row order — is inverse-permuted
-    before the residual/ReLU epilogue."""
+    before the residual/ReLU epilogue.
+
+    The per-(sample × channel) chunk counts are scalar-prefetched into SMEM.
+    Ids and values enter as ``(chunks, CHUNK)`` panels per channel as
+    int32/f32: Mosaic cannot load one packed 16-bit chunk-row at a dynamic
+    offset, so the bf16 variant keeps bf16 storage for X/W/bias only."""
     interpret = resolve_interpret(interpret)
     assert (rank is None) == (slab is None), "rank/slab must be set together"
     d_pad = 0 if slab is None else slab.shape[2]
@@ -165,6 +172,9 @@ def fused_forward(
         nnz_pad += pad
     total_chunks = nnz_pad // CHUNK
 
+    def panel(t, dtype):
+        return t.astype(dtype).reshape(batch, channels, total_chunks, CHUNK)
+
     n_block, p = plan.n_block, plan.p
     if n_out % n_block:
         padc = p * n_block - n_out
@@ -173,25 +183,26 @@ def fused_forward(
         if residual is not None:
             residual = jnp.pad(residual, ((0, 0), (0, 0), (0, padc)))
 
+    idx_spec = pl.BlockSpec((1, channels, total_chunks, CHUNK),
+                            lambda i, j, *_: (i, 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, channels), lambda i, j: (i, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, channels, nnz_pad), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, channels, nnz_pad), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, channels, nnz_pad), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, m_pad, n_in), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((channels, n_in, n_block), lambda i, j: (0, 0, j)),
-        pl.BlockSpec((channels, n_block), lambda i, j: (0, j)),
+        idx_spec, idx_spec, idx_spec,
+        pl.BlockSpec((1, m_pad, n_in), lambda i, j, *_: (i, 0, 0)),
+        pl.BlockSpec((channels, n_in, n_block), lambda i, j, *_: (0, 0, j)),
+        pl.BlockSpec((channels, n_block), lambda i, j, *_: (0, j)),
     ]
-    operands = [chunks.astype(jnp.int32), row_ids, col_ids, values, x, w, bias]
+    operands = [chunks.astype(jnp.int32).reshape(-1),
+                panel(row_ids, jnp.int32), panel(col_ids, jnp.int32),
+                panel(values, jnp.float32), x, w, bias]
     if d_pad:
-        in_specs.append(pl.BlockSpec((1, m_pad), lambda i, j: (i, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, m_pad),
+                                     lambda i, j, *_: (i, 0, 0)))
         in_specs.append(pl.BlockSpec((1, channels, d_pad, m_pad),
-                                     lambda i, j: (i, 0, 0, 0)))
-        operands += [rank.astype(jnp.int32), slab]
+                                     lambda i, j, *_: (i, 0, 0, 0)))
+        operands += [rank.astype(jnp.int32).reshape(batch, 1, m_pad), slab]
     if residual is not None:
         in_specs.append(pl.BlockSpec((1, m_pad, n_block),
-                                     lambda i, j: (i, 0, j)))
+                                     lambda i, j, *_: (i, 0, j)))
         operands.append(residual)
 
     out = pl.pallas_call(
@@ -199,9 +210,13 @@ def fused_forward(
             _kernel, channels=channels, total_chunks=total_chunks,
             epilogue=epilogue, has_residual=residual is not None,
             d_pad=d_pad),
-        grid=(batch, p),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, m_pad, n_block), lambda i, j: (i, 0, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, p),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, m_pad, n_block),
+                                   lambda i, j, *_: (i, 0, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((batch, m_pad, p * n_block), x.dtype),
         interpret=interpret,
     )(*operands)
@@ -382,24 +397,26 @@ def fused_graph_conv(
     ``sample_chunks`` when host-side nnz metadata is available so the
     packing decision is recorded statically too.
     """
+    from repro.autotune.cost_model import precision_of
+    from repro.kernels.ops import bwd_impl_for
+
     interpret = resolve_interpret(interpret)
     batch, channels, nnz_pad = row_ids.shape
+    hybrid = precision_of(impl)[0] == "fused_hybrid"
     if plan is None:
         plan = plan_fused_graph_conv(
             batch=batch, m_pad=x.shape[1], n_in=x.shape[2], n_out=w.shape[-1],
-            channels=channels, nnz_pad=nnz_pad, itemsize=x.dtype.itemsize)
+            channels=channels, nnz_pad=nnz_pad, itemsize=x.dtype.itemsize,
+            hybrid=hybrid)
     if plan.case == 3:
         raise ValueError(
-            f"m_pad={plan.m_pad} is planner case 3 (> LARGE_M): the fused "
-            "megakernel does not batch matrices this large — use the unfused "
-            "graph_conv_batched fallback")
+            f"m_pad={plan.m_pad} is planner case 3 (> LARGE_M, or a working "
+            "set past VMEM): the fused megakernel does not batch matrices "
+            "this large — use the unfused graph_conv_batched fallback")
     chunks = runtime_chunks(nnz)
-    from repro.autotune.cost_model import precision_of
-    from repro.kernels.ops import bwd_impl_for
     bwd_impl = bwd_impl_for(impl) if not interpret else "ref"
     has_res = residual is not None
     rids, cids = row_ids, col_ids
-    hybrid = precision_of(impl)[0] == "fused_hybrid"
     if hybrid:
         # hub-ness is judged on the layer's whole edge budget (all channels)
         hplan = plan_hybrid(batch=batch, m_pad=plan.m_pad, n_b=w.shape[-1],

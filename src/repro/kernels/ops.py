@@ -33,6 +33,7 @@ from repro.autotune.cost_model import (
     PRECISION_IMPLS,
     precision_of,
     supports_gspmm,
+    tpu_refusal,
 )
 from repro.observability import trace as obs_trace
 from repro.core import batching
@@ -209,7 +210,14 @@ def _forward(row_ids, col_ids, nnz, values, b, *, impl, k_pad, interpret,
 
     A non-default ``(op, reduce)`` or 3D (vector-edge) ``values`` routes to
     the g-SpMM dispatch (:func:`_gspmm_forward`): f32-only, explicit padding
-    masks, restricted to ``autotune.GSPMM_IMPLS``."""
+    masks, restricted to ``autotune.GSPMM_IMPLS``.
+
+    A compiled call (``interpret=False``) naming a kernel the TPU refuses
+    (``autotune.cost_model.TPU_REFUSED``) raises here, before Mosaic."""
+    refused = None if interpret else tpu_refusal(impl, reduce)
+    if refused:
+        raise ValueError(f"impl {impl!r} does not compile for the TPU: "
+                         f"{refused}")
     if (op, reduce) != ("mul", "sum") or values.ndim == 3:
         if not supports_gspmm(impl):
             raise ValueError(
@@ -298,7 +306,7 @@ def _forward_base(row_ids, col_ids, nnz, values, b, *, impl, base, k_pad,
     plan = batching.plan_batched_spmm(
         batch=batch, m_pad=m_pad, n_b=n_b,
         slots=k_pad if base == "pallas_ell" else row_ids.shape[1],
-        itemsize=b.dtype.itemsize,
+        itemsize=b.dtype.itemsize, onehot=base == "pallas_coo",
     )
     if plan.case == 3:
         # Paper case 3: matrices too large for the batched shared-memory
@@ -314,11 +322,8 @@ def _forward_base(row_ids, col_ids, nnz, values, b, *, impl, base, k_pad,
         return batched_spmm_ell(cids, ell.values, b, plan=plan,
                                 scale=scale, interpret=interpret)
     if base == "pallas_coo":
-        rids, cids = row_ids, col_ids
-        if narrow:
-            rids = narrow_col_ids(rids, m_pad)
-            cids = narrow_col_ids(cids, m_pad)
-        return batched_spmm_coo(rids, cids, values, b, plan=plan,
+        # no int16 narrowing: the kernel reads int32 chunk-rows
+        return batched_spmm_coo(row_ids, col_ids, values, b, plan=plan,
                                 interpret=interpret)
     raise ValueError(f"unknown impl {impl!r}; expected one of {IMPLS}")
 
@@ -383,7 +388,7 @@ def _gspmm_forward(row_ids, col_ids, nnz, values, b, *, impl, k_pad,
     if impl == "pallas_coo":
         plan = batching.plan_batched_spmm(
             batch=batch, m_pad=m_pad, n_b=n_b, slots=row_ids.shape[1],
-            itemsize=b.dtype.itemsize)
+            itemsize=b.dtype.itemsize, onehot=True)
         if plan.case == 3:
             return fallback()
         return batched_spmm_coo(row_ids, col_ids, values, b, plan=plan,

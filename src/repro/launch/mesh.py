@@ -7,9 +7,8 @@ count while tests/benchmarks must see one real device.
 from __future__ import annotations
 
 import jax
-
-
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,10 +24,19 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"production mesh needs {need} devices, have {len(devices)} — "
             "run via repro.launch.dryrun (it forces 512 host devices)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return make_mesh(shape, axes, devices=devices[:need])
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Elastic entry point: trainer restart on a different device count simply
-    re-lowers against a new mesh (sharding rules are mesh-parametric)."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
+              devices=None):
+    """The one mesh constructor of the repo: every axis is ``Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which a plain slice
+    of a batch-sharded array (the padded-batch ``out[:batch]`` of
+    ``distributed/spmm.py``) or a ``jnp.take`` raises ``ShardingTypeError``;
+    the code here relies on GSPMD propagation instead. Elastic: a trainer
+    restart on a different device count simply re-lowers against a new mesh
+    (sharding rules are mesh-parametric). ``devices`` defaults to the first
+    ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)

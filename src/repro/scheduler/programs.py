@@ -68,9 +68,6 @@ class ProgramCache:
 
     def jit_cache_sizes(self) -> dict[str, int]:
         """tier key → entries in the tier engine's jit cache. The one-
-        compilation-per-tier invariant holds iff every value is 1. Tiers
-        whose runtime cannot report a count (no jit introspection) are
-        omitted rather than guessed."""
-        sizes = {t.key: p.engine.compiled_programs()
-                 for t, p in self._programs.items()}
-        return {k: v for k, v in sizes.items() if v is not None}
+        compilation-per-tier invariant holds iff every value is 1."""
+        return {t.key: p.engine.compiled_programs()
+                for t, p in self._programs.items()}

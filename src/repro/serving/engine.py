@@ -372,16 +372,12 @@ class GraphServeEngine:
     # _serve_in_waves drives waves through the same public executor
     _run_wave = run_wave
 
-    def compiled_programs(self) -> int | None:
+    def compiled_programs(self) -> int:
         """Entries in this engine's jit cache — 1 is the one-program-per-
         geometry invariant the scheduler's program cache relies on. The
-        count comes from JAX's private ``_cache_size`` introspection helper;
-        None when that helper is unavailable (this method is the ONE place
-        that dependency lives)."""
-        try:
-            return self._apply._cache_size()
-        except AttributeError:
-            return None
+        count comes from JAX's private ``_cache_size`` introspection helper
+        (this method is the ONE place that dependency lives)."""
+        return self._apply._cache_size()
 
     def run(self, requests: list[GraphRequest]) -> list[GraphRequest]:
         return _serve_in_waves(self, requests)

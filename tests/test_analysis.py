@@ -67,7 +67,8 @@ sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.analysis.hlo_cost import analyze_text
-mesh = jax.make_mesh((4,), ("x",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("x",))
 def f(a):
     return jax.lax.with_sharding_constraint(
         a.sum(axis=0, keepdims=True), NamedSharding(mesh, P()))

@@ -12,6 +12,7 @@ from repro.autotune import (
     TuningCache,
     Workload,
     autotune,
+    estimate,
     measure_workload,
     rank,
     select_impl,
@@ -75,13 +76,17 @@ def test_selector_skewed_degree_picks_csr_class():
     flat nnz traffic, rpt-bounded loop — beats ELL's padded m_pad·k_pad
     slots (GE-SpMM's skewed-degree case, DESIGN.md §9)."""
     w = Workload(batch=100, m_pad=2048, nnz_pad=8192, k_pad=8, n_b=512)
-    d = select_impl(w)
-    assert d.kind == "csr" and d.impl == "pallas_csr", d
-    # the XLA csr fallback is a segment-sum — same scatter traffic as ref
-    # plus the rpt arrays — so the CPU posture legitimately keeps the
-    # scatter class; only the Pallas row-split kernel monetizes the layout
-    d_cpu = select_impl(w, allow_pallas=False)
-    assert d_cpu.kind in ("csr", "scatter")
+    # the model still prices the Pallas row-split under the padded ELL
+    # kernel — that is the layout argument...
+    assert estimate(w, "pallas_csr") < estimate(w, "pallas_ell")
+    # ...but Mosaic refuses pallas_csr for the TPU (cost_model.TPU_REFUSED),
+    # so neither posture can pick it. The XLA csr fallback is a segment-sum —
+    # same scatter traffic as ref plus the rpt arrays — so both postures
+    # keep the scatter/csr class, and neither pays ELL's padded slots
+    for allow_pallas in (True, False):
+        d = select_impl(w, allow_pallas=allow_pallas)
+        assert d.kind in ("csr", "scatter"), d
+        assert "pallas_csr" not in {i for i, _ in d.scores}
 
 
 def test_csr_runnable_without_k_pad():
@@ -174,13 +179,16 @@ def test_case_boundaries():
     d1 = select_impl(w1)
     assert d1.case == 1 and d1.plan.p == 1
     # case 2: same rows, wide n_b → panels. Avg degree (nnz_pad/m_pad = 4)
-    # is half of k_pad=8, so the row-split class that wins is CSR — flat
-    # nnz traffic — not ELL, which pays the padded m_pad·k_pad slots
-    # (GE-SpMM's skewed-degree case, DESIGN.md §9).
+    # is half of k_pad=8, so the model prices the CSR row-split — flat nnz
+    # traffic — under ELL, which pays the padded m_pad·k_pad slots
+    # (GE-SpMM's skewed-degree case, DESIGN.md §9). The Pallas CSR kernel is
+    # refused for the TPU (cost_model.TPU_REFUSED), so the compiled ladder
+    # lands elsewhere — but never on ELL.
     w2 = Workload(batch=10, m_pad=2048, nnz_pad=8192, k_pad=8, n_b=4096)
     d2 = select_impl(w2)
     assert d2.case == 2 and d2.plan.p > 1
-    assert d2.kind == "csr"
+    assert estimate(w2, "pallas_csr") < estimate(w2, "pallas_ell")
+    assert d2.kind != "ell"
     # case 3: over the LARGE_M threshold
     w3 = Workload(batch=2, m_pad=8200, nnz_pad=16400, k_pad=8, n_b=64)
     d3 = select_impl(w3)
@@ -298,6 +306,31 @@ def test_measure_workload_returns_sane_times():
                              warmup=1, iters=2)
     assert set(times) == {"ref", "dense"}
     assert all(0 < t < 60 for t in times.values())
+
+
+def test_measure_workload_back_to_back_calls(monkeypatch):
+    """``calls`` times that many back-to-back calls per timing, blocks once,
+    and reports the time per call."""
+    import jax
+
+    w = Workload(batch=2, m_pad=16, nnz_pad=32, k_pad=4, n_b=8)
+    blocks = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(1) or real(x))
+    times = measure_workload(w, ("ref",), interpret=True, warmup=1, iters=3,
+                             calls=4)
+    assert len(blocks) == 1 + 3          # warm-up, then once per timing
+    assert 0 < times["ref"] < 60
+
+
+def test_measure_workload_raises_on_failing_candidate():
+    """A candidate that cannot compile or run must not vanish from the
+    record in silence: measuring it raises, naming the impl."""
+    w = Workload(batch=2, m_pad=16, nnz_pad=32, k_pad=None, n_b=8)
+    with pytest.raises(RuntimeError, match="'pallas_ell'"):
+        measure_workload(w, ("ref", "pallas_ell"), interpret=True,
+                         warmup=1, iters=1)
 
 
 # ---------------------------------------------------------------------------

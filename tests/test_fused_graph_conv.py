@@ -213,10 +213,14 @@ def test_plan_fused_panels_wide_output():
     assert plan.n_block % batching.LANES == 0
     assert plan.bytes_per_step <= batching.VMEM_TILE_BUDGET * 1.01
     # the X panel + indices are fixed costs the column split cannot shrink:
-    # with a huge n_in the plan bottoms out at one-lane-tile panels
-    floor = plan_fused_graph_conv(batch=8, m_pad=2048, n_in=2048, n_out=4096,
+    # with a large n_in the plan bottoms out at one-lane-tile panels
+    floor = plan_fused_graph_conv(batch=8, m_pad=1024, n_in=512, n_out=4096,
                                   channels=4, nnz_pad=8192)
-    assert floor.n_block == batching.LANES
+    assert floor.n_block == batching.LANES and floor.case == 2
+    # ... and an X panel that alone overflows the scoped VMEM is not offered
+    huge = plan_fused_graph_conv(batch=8, m_pad=2048, n_in=2048, n_out=4096,
+                                 channels=4, nnz_pad=8192)
+    assert huge.case == 3
 
 
 def test_fused_rejects_case3():
@@ -239,9 +243,10 @@ def test_fused_rejects_case3():
 def test_autotuner_selects_fused_for_gcn_layer():
     from repro.autotune import Workload, rank_layer, select_graph_conv_impl
 
-    # tox21-like layer: nnz_pad is the batch max, the MEAN nnz (skew knob)
-    # is what the fused kernel's per-sample loop actually pays
-    w = Workload(batch=100, m_pad=56, nnz_pad=512, k_pad=8, n_b=64,
+    # a layer of larger graphs over a skewed batch: nnz_pad is the batch
+    # max, the MEAN nnz (skew knob) is what the fused kernel's per-sample
+    # loop actually pays
+    w = Workload(batch=100, m_pad=256, nnz_pad=4096, k_pad=8, n_b=64,
                  channels=4, n_in=62, nnz_avg=128)
     d = select_graph_conv_impl(w, allow_pallas=True)
     assert d.impl == "fused" and d.kind == "fused" and d.source == "model"
@@ -416,3 +421,38 @@ def test_default_interpret_resolver(monkeypatch):
     monkeypatch.setenv("REPRO_INTERPRET", "maybe")
     with pytest.raises(ValueError, match="REPRO_INTERPRET"):
         default_interpret()
+
+
+def test_require_compiled_refuses_cpu_and_interpret(monkeypatch):
+    """The compiled-posture guard: a non-TPU backend raises, and on a TPU
+    REPRO_INTERPRET=1 or an explicit interpret=True raises too — while
+    default_interpret()'s CPU behaviour stays as it was."""
+    import jax
+
+    from repro.kernels import default_interpret, require_compiled
+
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        require_compiled()
+    assert default_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    require_compiled()                          # TPU, compiled: passes
+    require_compiled(False)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        require_compiled(True)
+    monkeypatch.setenv("REPRO_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="REPRO_INTERPRET=1"):
+        require_compiled()
+
+
+def test_compile_cache_default_dir_is_fixed_and_ignored():
+    """Without $JAX_COMPILATION_CACHE_DIR the entry points cache compiles in
+    one fixed directory inside the checkout, which git ignores."""
+    import pathlib
+
+    from repro import compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == root / ".jax_cache"
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

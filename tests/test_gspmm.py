@@ -467,7 +467,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.formats import random_batch
 from repro.distributed.spmm import sharded_batched_gspmm
 from repro.kernels.ops import batched_gspmm
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 a, m_pad = random_batch(rng, batch=12, dim=24, nnz_per_row=3)  # 12 % 8 != 0
 b = jnp.asarray(rng.standard_normal((12, m_pad, 16)), jnp.float32)

@@ -30,7 +30,7 @@ import numpy as np
 import pytest
 
 from oracle import CONCRETE_SPMM_IMPLS, tols_for
-from repro.autotune.cost_model import Workload, estimate, rank
+from repro.autotune.cost_model import Workload, estimate, rank, tpu_refusal
 from repro.autotune.selector import KINDS
 from repro.core.batching import HYBRID_TAU, SUBLANES, plan_hybrid
 from repro.core.formats import (
@@ -257,8 +257,12 @@ def test_hybrid_kinds_registered():
     assert KINDS["hybrid"] == KINDS["pallas_hybrid"] == "hybrid"
     assert KINDS["pallas_hybrid_bf16"] == "hybrid"
     assert KINDS["fused_hybrid"] == "fused"
+    # the XLA sibling ranks on both ladders; the Pallas kernel is on
+    # neither: interpret mode never ranks Pallas, and Mosaic refuses
+    # pallas_hybrid for the TPU (cost_model.TPU_REFUSED)
     ranked = [i for i, _ in rank(_SKEW_W, allow_pallas=True)]
-    assert "pallas_hybrid" in ranked and "hybrid" in ranked
+    assert "hybrid" in ranked and "pallas_hybrid" not in ranked
+    assert tpu_refusal("pallas_hybrid") and tpu_refusal("pallas_hybrid_bf16")
     assert "pallas_hybrid" not in [
         i for i, _ in rank(_SKEW_W, allow_pallas=False)]
 
@@ -319,7 +323,8 @@ coo, m_pad = random_powerlaw_batch(rng, batch=5, dim=24, avg_deg=4)
 adj = [coo, coo]
 x = jnp.asarray(rng.normal(size=(5, m_pad, 8)), jnp.float32)
 params = init_graph_conv(jax.random.PRNGKey(1), 8, 16, 2)
-mesh = jax.make_mesh((2,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("data",))
 def run(mesh=None):
     def loss(xx):
         return jnp.sum(jnp.sin(graph_conv_batched(

@@ -342,10 +342,13 @@ def test_workload_block_axis_key_and_selection():
                       max_deg=16)
     assert "_blk" not in legacy.key()
     # a skewed sampled block (few output rows, bounded row degree, wide
-    # src padding) must route to the row-split CSR class on the TPU model —
-    # dense/ELL still pay the full m_pad² / m_pad·k_pad geometry
+    # src padding) must route to a class that pays per real non-zero on the
+    # TPU ladder — dense/ELL still pay the full m_pad² / m_pad·k_pad
+    # geometry. The Pallas row-split kernels are refused for the TPU
+    # (cost_model.TPU_REFUSED), so that class is the XLA scatter/CSR pair
     d = select_impl(w, allow_pallas=True)
-    assert d.impl in ("pallas_csr", "pallas_hybrid"), d
+    assert d.kind in ("scatter", "csr"), d
+    assert d.kind not in ("gemm", "ell"), d
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.formats import random_batch
 from repro.distributed.spmm import resolve_sharded_impl, sharded_batched_spmm
 from repro.kernels.ops import batched_spmm
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 """
 

@@ -37,7 +37,9 @@ def test_constrain_is_identity_without_mesh():
 
 
 def test_constrain_skips_nondivisible():
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("model",))
     with tuning.use_mesh_hint(mesh):
         assert tuning.axis_size("model") == 1
         x = jnp.ones((7, 16))
