@@ -9,7 +9,9 @@ dense prediction head. Two task heads match the evaluation datasets:
 
 The model is pure-functional (init/apply), with ``batched=True`` selecting the
 Fig. 7 execution and ``batched=False`` the Fig. 6 baseline — identical
-numerics, different op structure.
+numerics, different op structure. Conv layer ``i`` is traced under
+``jax.named_scope(f"conv{i}")``, so its ops (and, in a gradient, their
+transposes) carry the layer in their HLO metadata.
 """
 from __future__ import annotations
 
@@ -199,6 +201,25 @@ def _batch_norm(p, x, mask, mode: str = "batch"):
     return xn * p["scale"] + p["bias"]
 
 
+def _conv(conv_p, cfg: GCNConfig, adj, h, mesh):
+    """One conv layer of kind ``cfg.layer`` (DESIGN.md §11)."""
+    if cfg.layer == "gat":
+        from repro.models.gnn import gat_layer
+
+        return gat_layer(conv_p, adj[0], h, impl=cfg.impl, k_pad=cfg.k_pad,
+                         interpret=cfg.interpret, mesh=mesh)
+    if cfg.layer == "rgcn":
+        from repro.models.gnn import rgcn_layer
+
+        return rgcn_layer(conv_p, adj, h, impl=cfg.impl, k_pad=cfg.k_pad,
+                          interpret=cfg.interpret, mesh=mesh)
+    if cfg.batched:
+        return graph_conv_batched(conv_p, adj, h, impl=cfg.impl,
+                                  k_pad=cfg.k_pad, interpret=cfg.interpret,
+                                  mesh=mesh, precision=cfg.precision)
+    return graph_conv_nonbatched(conv_p, adj, h)
+
+
 def apply_gcn(
     params,
     cfg: GCNConfig,
@@ -216,23 +237,9 @@ def apply_gcn(
         # Fig. 6 per-sample baseline for them
         raise ValueError(f"layer={cfg.layer!r} requires batched=True")
     h = x
-    for conv_p, bn_p in zip(params["convs"], params["bns"]):
-        if cfg.layer == "gat":
-            from repro.models.gnn import gat_layer
-
-            h = gat_layer(conv_p, adj[0], h, impl=cfg.impl, k_pad=cfg.k_pad,
-                          interpret=cfg.interpret, mesh=mesh)
-        elif cfg.layer == "rgcn":
-            from repro.models.gnn import rgcn_layer
-
-            h = rgcn_layer(conv_p, adj, h, impl=cfg.impl, k_pad=cfg.k_pad,
-                           interpret=cfg.interpret, mesh=mesh)
-        elif cfg.batched:
-            h = graph_conv_batched(conv_p, adj, h, impl=cfg.impl,
-                                   k_pad=cfg.k_pad, interpret=cfg.interpret,
-                                   mesh=mesh, precision=cfg.precision)
-        else:
-            h = graph_conv_nonbatched(conv_p, adj, h)
+    for i, (conv_p, bn_p) in enumerate(zip(params["convs"], params["bns"])):
+        with jax.named_scope(f"conv{i}"):
+            h = _conv(conv_p, cfg, adj, h, mesh)
         h = _batch_norm(bn_p, h * mask, mask, cfg.bn_mode)
         h = jax.nn.relu(h) * mask
     readout = jnp.sum(h, axis=1)                          # masked sum readout
@@ -278,9 +285,10 @@ def apply_gcn_blocks(
         mask = (
             jnp.arange(h.shape[1])[None, :, None] < adj.n_rows[0]
         ).astype(h.dtype)
-        h = graph_conv_batched(conv_p, [adj], h, impl=impls[i],
-                               k_pad=cfg.k_pad, interpret=cfg.interpret,
-                               precision=cfg.precision)
+        with jax.named_scope(f"conv{i}"):
+            h = graph_conv_batched(conv_p, [adj], h, impl=impls[i],
+                                   k_pad=cfg.k_pad, interpret=cfg.interpret,
+                                   precision=cfg.precision)
         h = _batch_norm(bn_p, h * mask, mask, cfg.bn_mode)
         h = jax.nn.relu(h) * mask
         if i + 1 < len(adjs):

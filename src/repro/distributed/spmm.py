@@ -191,18 +191,15 @@ def sharded_batched_spmm(
         return bwd_sharded(row_ids, col_ids, nnz, values, bb, dc)
 
     f.defvjp(fwd, bwd)
-    if obs_trace.enabled():
-        # distributed-layer span (DESIGN.md §13): the per-SHARD workload key
-        # is the decision's provenance — the same key the regret auditor and
-        # tuning cache use for this dispatch's shapes
-        w = decision.workload
-        with obs_trace.TRACER.span(
-                f"sharded_spmm/{concrete}", cat="kernel",
-                args={"impl": concrete, "source": decision.source,
-                      "n_shards": n, "padded": bool(pad),
-                      "key": None if w is None else w.key()}):
-            out = f(a.values, b)
-    else:
+    # distributed-layer span (DESIGN.md §13): the per-SHARD workload key is
+    # the decision's provenance — the same key the regret auditor and
+    # tuning cache use for this dispatch's shapes
+    w = decision.workload
+    args = {"impl": concrete, "source": decision.source, "n_shards": n,
+            "padded": bool(pad), "key": None if w is None else w.key()} \
+        if obs_trace.enabled() else None
+    with jax.named_scope(f"spmm/{concrete}"), obs_trace.span(
+            f"sharded_spmm/{concrete}", cat="kernel", args=args):
         out = f(a.values, b)
     return out[:batch] if pad else out
 

@@ -402,13 +402,14 @@ def _traced_dispatch(f, values, b, *, impl, decision, workload):
     """Run one dispatch under a telemetry span (DESIGN.md §13).
 
     Only reached when ``observability.enabled()`` — the hot path pays a
-    single predicate otherwise. The span carries the workload geometry, the
-    auto-decision provenance, and the cost model's *predicted* seconds and
-    minimum HBM bytes, so a trace viewer (and the regret auditor) can line
-    predicted up against measured. Eager (non-traced) dispatches also feed
-    the default regret auditor's online calibration stream; traced calls
-    record the span (trace-time wall) but skip the auditor — a trace is not
-    an execution.
+    single predicate otherwise. The caller's ``spmm/<impl>`` name scope is
+    on either way, so the compiled program is the same. The span carries
+    the workload geometry, the auto-decision provenance, and the cost
+    model's *predicted* seconds and minimum HBM bytes, so a trace viewer
+    (and the regret auditor) can line predicted up against measured. Eager
+    (non-traced) dispatches also feed the default regret auditor's online
+    calibration stream; traced calls record the span (trace-time wall) but
+    skip the auditor — a trace is not an execution.
     """
     from repro.autotune.cost_model import estimate
     from repro.observability.regret import default_auditor
@@ -710,11 +711,12 @@ def batched_gspmm(
         return dval, db
 
     f.defvjp(fwd, bwd)
-    if tele and gdecision is not None and gdecision.workload is not None:
-        return _traced_dispatch(f, a.values, b, impl=impl,
-                                decision=gdecision,
-                                workload=gdecision.workload)
-    return f(a.values, b)
+    with jax.named_scope(f"spmm/{impl}"):
+        if tele and gdecision is not None and gdecision.workload is not None:
+            return _traced_dispatch(f, a.values, b, impl=impl,
+                                    decision=gdecision,
+                                    workload=gdecision.workload)
+        return f(a.values, b)
 
 
 def batched_spmm(
@@ -788,11 +790,12 @@ def batched_spmm(
         return dval, db.astype(b.dtype)
 
     f.defvjp(fwd, bwd)
-    if tele and decision is not None and decision.workload is not None:
-        return _traced_dispatch(f, a.values, b, impl=impl,
-                                decision=decision,
-                                workload=decision.workload)
-    return f(a.values, b)
+    with jax.named_scope(f"spmm/{impl}"):
+        if tele and decision is not None and decision.workload is not None:
+            return _traced_dispatch(f, a.values, b, impl=impl,
+                                    decision=decision,
+                                    workload=decision.workload)
+        return f(a.values, b)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1,14 +1,16 @@
 """Unified telemetry layer (DESIGN.md §13): span tracer + metrics registry +
 autotune regret auditing.
 
-Three pieces, one import surface:
+Four pieces, one import surface:
 
-- :mod:`repro.observability.trace` — nested spans into a process-local ring
-  buffer with a Chrome-trace/Perfetto exporter and
-  ``jax.profiler.TraceAnnotation``/``named_scope`` bridging. Hot-path spans
-  (kernel dispatch) are gated by ``REPRO_TELEMETRY`` (default off);
-  structural spans (train step, serve wave, scheduler lifecycle) record
-  unconditionally unless the emitting object is built ``telemetry=False``.
+- :mod:`repro.observability.trace` — spans that always enter a
+  ``jax.profiler.TraceAnnotation`` (the device-trace view) and, under
+  ``REPRO_TELEMETRY`` (default off), also record into a process-local ring
+  buffer with a Chrome-trace/Perfetto exporter (the operator's view). The
+  emitting object takes ``telemetry=False`` to drop both.
+- :mod:`repro.observability.collector` — one ``gc.callbacks`` hook,
+  installed on import: every collection runs inside a ``host/gc``
+  annotation and counts on the ``process_gc_*`` counters.
 - :mod:`repro.observability.metrics` — counters/gauges/fixed-bucket
   histograms with labeled series and a JSON-lines snapshot exporter;
   ``ServeMetrics`` and the trainer hooks sit on this registry.
@@ -16,6 +18,7 @@ Three pieces, one import surface:
   predicted-vs-measured per (impl, workload-key), flagged regret, and
   would-have-won alternatives.
 """
+from repro.observability import collector
 from repro.observability.metrics import (  # noqa: F401
     DEFAULT_TIME_BUCKETS,
     REGISTRY,
@@ -37,6 +40,8 @@ from repro.observability.trace import (  # noqa: F401
     span,
     telemetry,
 )
+
+collector.install()
 
 __all__ = [
     "AUDITOR", "Counter", "DEFAULT_TIME_BUCKETS", "ENV_VAR", "Gauge",

@@ -77,6 +77,11 @@ class Counter(_Metric):
         box = self._series.get(_label_key(labels))
         return box[0] if box else 0.0
 
+    def cell(self, **labels) -> list[float]:
+        """The one-element box behind a series: bound once, ``box[0] += v``
+        adds to it with no lookup and no allocation."""
+        return self._get(labels, lambda: [0.0])
+
     def total(self) -> float:
         return sum(box[0] for box in self._series.values())
 

@@ -1,35 +1,43 @@
 """Span tracer: nested spans, a ring buffer, Chrome-trace export (DESIGN.md
 §13).
 
-One process-local :class:`Tracer` (the module singleton :data:`TRACER`)
-collects three kinds of events into a bounded ring buffer:
+One switch, two sinks:
 
-- **complete spans** (Chrome ``ph="X"``) — a named interval with wall-clock
-  ``ts``/``dur`` and structured ``args``; nested spans nest in the viewer by
-  timestamp containment on the same track;
-- **instant events** (``ph="i"``) — a point marker (request arrival, admit);
-- **counter samples** (``ph="C"``) — a named scalar over time (queue depth).
+- **profiler annotations, always.** :meth:`Tracer.span` always enters a
+  ``jax.profiler.TraceAnnotation``. With no profiler running that costs
+  well under a microsecond; under ``jax.profiler.trace`` the span lands on
+  the host-thread track, on the same clock as XLA's device timeline, which
+  is what a device-trace reduction reads.
+- **the ring buffer, under** ``REPRO_TELEMETRY``. Only when
+  :func:`enabled` does the process-local :class:`Tracer` (the module
+  singleton :data:`TRACER`) keep events in its bounded ring, for the
+  operator's Chrome-trace/Perfetto export:
 
-Two clock domains share the file: spans opened with :meth:`Tracer.span` are
-stamped from ``time.perf_counter`` (the process wall clock); scheduler
-lifecycle events carry the *scheduler's* clock (possibly a
+  - **complete spans** (Chrome ``ph="X"``) — a named interval with
+    wall-clock ``ts``/``dur`` and structured ``args``; nested spans nest in
+    the viewer by timestamp containment on the same track;
+  - **instant events** (``ph="i"``) — a point marker (request arrival,
+    admit);
+  - **counter samples** (``ph="C"``) — a named scalar over time (queue
+    depth).
+
+  Callers build ``args`` dicts only when :func:`enabled`; with the ring off
+  no event object is made at all.
+
+Two clock domains share the exported file: spans opened with
+:meth:`Tracer.span` are stamped from ``time.perf_counter`` (the process wall
+clock); scheduler lifecycle events carry the *scheduler's* clock (possibly a
 ``VirtualClock``) and live on their own ``tid`` track so the two timelines
 never interleave confusingly.
 
-**Hot-path gating**: the module-level :func:`span` checks :func:`enabled`
-(the ``REPRO_TELEMETRY`` env var, default off) before doing ANY work and
-returns a shared null context when disabled — that one predicate is the
-entire disabled-mode cost, which the overhead-guard test bounds at < 5% of
-a single XLA dispatch. Structural spans (trainer steps, serve waves,
-scheduler waves) call :meth:`Tracer.span` directly: they are emitted
-unconditionally because their cost is negligible next to the work they
-measure, and the emitting object takes ``telemetry=False`` to opt out.
+Host spans do not enter ``jax.named_scope``: around a call to a compiled
+function a name scope does nothing at run time. Name scopes live where the
+program is traced (``conv<i>`` per conv layer in ``core/gcn.py``,
+``spmm/<impl>`` per SpMM dispatch in ``kernels/ops.py``), so traced and
+untraced runs compile the same HLO.
 
-**XLA bridging**: every span also enters ``jax.profiler.TraceAnnotation``
-(so a concurrent ``jax.profiler.trace`` capture shows our spans on the
-host-thread track, aligned with XLA's own device timeline) and
-``jax.named_scope`` (so ops traced inside the span carry the span's name in
-the HLO metadata).
+The module-level :func:`span` is the kernel-dispatch hook: it returns a
+shared null context when telemetry is off, one predicate per dispatch.
 """
 from __future__ import annotations
 
@@ -73,8 +81,8 @@ _NULL = contextlib.nullcontext()
 
 
 def enabled() -> bool:
-    """Whether hot-path (kernel-dispatch) telemetry is on. This is the ONE
-    check `kernels/ops.py` pays per dispatch when telemetry is off."""
+    """Whether the ring buffer records (``REPRO_TELEMETRY``). This is the
+    ONE check `kernels/ops.py` pays per dispatch when telemetry is off."""
     return _STATE.enabled
 
 
@@ -124,21 +132,23 @@ class Tracer:
                 self.dropped += 1
             self._events.append(ev)
 
-    @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "repro",
-             args: dict | None = None, annotate: bool = True):
-        """Record one complete span around the body. ``annotate=True`` also
-        enters the jax profiler annotation + named_scope so the span lines
-        up with XLA's own profile and names traced ops."""
-        stack = contextlib.ExitStack()
-        if annotate:
-            import jax
+             args: dict | None = None):
+        """A span around the body: a profiler annotation, and with
+        telemetry on one complete event in the ring."""
+        if _STATE.enabled:
+            return self._recorded(name, cat, args)
+        import jax
 
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
-            stack.enter_context(jax.named_scope(name))
+        return jax.profiler.TraceAnnotation(name)
+
+    @contextlib.contextmanager
+    def _recorded(self, name: str, cat: str, args: dict | None):
+        import jax
+
         t0 = time.perf_counter()
         try:
-            with stack:
+            with jax.profiler.TraceAnnotation(name):
                 yield self
         finally:
             t1 = time.perf_counter()
@@ -150,19 +160,27 @@ class Tracer:
                  tid: int | str = "clock", cat: str = "repro",
                  args: dict | None = None) -> None:
         """Record a complete span with CALLER-owned timestamps (seconds) —
-        the scheduler's virtual-clock lifecycle track."""
+        the scheduler's virtual-clock lifecycle track. Telemetry on only."""
+        if not _STATE.enabled:
+            return
         self._append(TraceEvent(name=name, ph="X", ts=ts * 1e6,
                                 dur=dur * 1e6, tid=tid, cat=cat, args=args))
 
     def instant(self, name: str, *, ts: float | None = None,
                 tid: int | str = "clock", cat: str = "repro",
                 args: dict | None = None) -> None:
+        """A point marker; telemetry on only."""
+        if not _STATE.enabled:
+            return
         ts = time.perf_counter() if ts is None else ts
         self._append(TraceEvent(name=name, ph="i", ts=ts * 1e6,
                                 tid=tid, cat=cat, args=args))
 
     def counter(self, name: str, value: float, *, ts: float | None = None,
                 tid: int | str = "clock", cat: str = "repro") -> None:
+        """A counter sample; telemetry on only."""
+        if not _STATE.enabled:
+            return
         ts = time.perf_counter() if ts is None else ts
         self._append(TraceEvent(name=name, ph="C", ts=ts * 1e6, tid=tid,
                                 cat=cat, args={"value": float(value)}))

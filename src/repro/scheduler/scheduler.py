@@ -29,6 +29,7 @@ import time
 from typing import Callable, Sequence
 
 from repro.core.gcn import GCNConfig
+from repro.observability import TRACER, enabled
 from repro.scheduler.bucketing import GeometryTier, TierPolicy
 from repro.scheduler.dispatcher import ContinuousDispatcher, Wait, WavePlan
 from repro.scheduler.metrics import ServeMetrics
@@ -102,12 +103,14 @@ class Scheduler:
     ``mesh=`` flows to every tier engine, so each wave spans the device mesh
     exactly as ``GraphServeEngine(mesh=...)`` waves do (DESIGN.md §6).
 
-    Telemetry (DESIGN.md §13): the request lifecycle —
-    arrival → admit → dispatch → finish — lands in the span tracer as
-    instants plus one complete span per request and per wave, stamped from
-    the SCHEDULER's clock (virtual or wall) on the shared ``tid="clock"``
-    track, with queue-depth counter samples at every admit/dispatch.
-    ``telemetry=False`` silences the trace feed; ``registry=`` hands
+    Telemetry (DESIGN.md §13): each wave runs in a ``sched/wave`` span and
+    each wait for an arrival or a flush in a ``sched/wait`` span (profiler
+    annotations). With ``REPRO_TELEMETRY`` on, the request lifecycle —
+    arrival → admit → dispatch → finish — also lands in the tracer's ring
+    as instants plus one complete span per request and per wave, stamped
+    from the SCHEDULER's clock (virtual or wall) on the shared
+    ``tid="clock"`` track, with queue-depth counter samples at every
+    admit/dispatch. ``telemetry=False`` silences both; ``registry=`` hands
     :class:`ServeMetrics` a shared metrics registry (plus ``instance``
     label) instead of its own.
     """
@@ -128,7 +131,6 @@ class Scheduler:
         registry=None,
         instance: str = "default",
     ):
-        from repro.observability import TRACER
         self.config = config or SchedulerConfig()
         if self.config.bn_mode != cfg.bn_mode:
             cfg = dataclasses.replace(cfg, bn_mode=self.config.bn_mode)
@@ -151,7 +153,6 @@ class Scheduler:
         self.buckets = {}
         self.telemetry = telemetry
         self.tracer = TRACER
-        self._calibrated: set[GeometryTier] = set()
         self.metrics = ServeMetrics(
             registry=registry,
             labels=None if registry is None else {"instance": instance})
@@ -174,17 +175,22 @@ class Scheduler:
             arrival = self.clock.now()
         if deadline is None and self.config.default_slo is not None:
             deadline = arrival + self.config.default_slo
-        if self.telemetry:
+        if self._ring():
             self.tracer.instant(
                 "request/arrival", ts=arrival, cat="sched",
                 args={"n_nodes": request.n_nodes,
                       "max_nnz": request.max_nnz, "deadline": deadline})
         return self.queue.submit(request, arrival=arrival, deadline=deadline)
 
+    def _ring(self) -> bool:
+        """Whether lifecycle events go to the tracer's ring."""
+        return self.telemetry and enabled()
+
     def _queue_depth(self) -> int:
         return sum(len(b) for b in self.buckets.values())
 
     def _admit(self, now: float) -> None:
+        ring = self._ring()
         admitted = False
         for p in self.queue.due(now):
             tier = self.policy.assign(p.request)
@@ -196,17 +202,17 @@ class Scheduler:
                     f"max_nnz={r.max_nnz} (top tier: {self.policy.tiers[-1]})")
                 self.metrics.record_rejection(arrival=p.arrival)
                 self.completed.append(p)
-                if self.telemetry:
+                if ring:
                     self.tracer.instant("request/reject", ts=now, cat="sched",
                                         args={"reason": r.error})
                 continue
             p.tier = tier
             self.buckets.setdefault(tier, collections.deque()).append(p)
             admitted = True
-            if self.telemetry:
+            if ring:
                 self.tracer.instant("request/admit", ts=now, cat="sched",
                                     args={"tier": tier.key})
-        if admitted and self.telemetry:
+        if admitted and ring:
             self.tracer.counter("queue_depth", self._queue_depth(), ts=now,
                                 cat="sched")
 
@@ -231,16 +237,13 @@ class Scheduler:
         # (bn_mode="sample": per-slot numerics), but keep it deterministic
         program = self.programs.get(plan.tier)
         dispatch = self.clock.now()
-        if self.telemetry:
-            # the wall-clock sched/wave span wraps the engine's serve/wave
-            # span (which wraps any trace-time kernel spans): the nested
-            # scheduler → wave → kernel structure the trace viewer shows
-            span = self.tracer.span(
-                "sched/wave", cat="sched",
-                args={"tier": plan.tier.key, "n_requests":
-                      sum(c for _, c in plan.takes)})
-        else:
-            span = contextlib.nullcontext()
+        ring = self._ring()
+        # the wall-clock sched/wave span wraps the engine's serve/wave span
+        # (which wraps any trace-time kernel spans): the nested scheduler →
+        # wave → kernel structure the trace viewer shows
+        span = self._span("sched/wave", {
+            "tier": plan.tier.key,
+            "n_requests": sum(c for _, c in plan.takes)} if ring else None)
         t0 = time.perf_counter()
         with span:
             report = program.engine.run_wave([p.request for p in wave])
@@ -251,9 +254,7 @@ class Scheduler:
         self.clock.on_service(service)
         finish = self.clock.now()
         self.metrics.record_wave(plan.tier.key, dispatch, service, report)
-        if self.telemetry:
-            self._feed_regret(plan.tier, program, measured)
-        if self.telemetry:
+        if ring:
             # clock-domain twin of the wall span: where the wave sits on the
             # scheduler's (possibly virtual) timeline
             self.tracer.complete(
@@ -267,7 +268,7 @@ class Scheduler:
                 arrival=p.arrival, dispatch=dispatch, finish=finish,
                 deadline=p.deadline, failed=p.request.failed)
             self.completed.append(p)
-            if self.telemetry:
+            if ring:
                 self.tracer.complete(
                     "request", ts=p.arrival, dur=max(finish - p.arrival, 0.0),
                     tid="requests", cat="sched",
@@ -276,34 +277,14 @@ class Scheduler:
                           "failed": bool(p.request.failed),
                           "deadline_missed": bool(
                               p.deadline is not None and finish > p.deadline)})
-        if self.telemetry:
+        if ring:
             self.tracer.counter("queue_depth", self._queue_depth(),
                                 ts=finish, cat="sched")
         self.metrics.compile_count = self.programs.compile_count
 
-    def _feed_regret(self, tier: GeometryTier, program, measured: float
-                     ) -> None:
-        """Wave-level calibration feed for the regret auditor: measured wave
-        wall time vs the tier decision's predicted first-layer kernel time.
-        Each tier's FIRST wave is skipped — it carries the compile, which
-        would poison the measured/predicted ratio by orders of magnitude.
-        The serve path's kernels only ever run inside the tier's jitted
-        program (no eager dispatch), so this is where serve-side
-        predicted-vs-measured provenance comes from (DESIGN.md §13)."""
-        if tier not in self._calibrated:
-            self._calibrated.add(tier)      # compile wave: record nothing
-            return
-        d = program.decision
-        w = getattr(d, "workload", None)
-        predicted = dict(getattr(d, "scores", ()) or ()).get(d.impl)
-        if predicted is None or predicted <= 0 or predicted != predicted \
-                or predicted == float("inf"):
-            return
-        from repro.observability import default_auditor
-
-        default_auditor().record(
-            w.key() if w is not None else tier.key, d.impl,
-            predicted_s=predicted, measured_s=measured)
+    def _span(self, name: str, args: dict | None = None):
+        return self.tracer.span(name, cat="sched", args=args) \
+            if self.telemetry else contextlib.nullcontext()
 
     def drain(self) -> list[PendingRequest]:
         """Event loop: admit arrivals, dispatch ready waves, wait (sleep or
@@ -325,7 +306,8 @@ class Scheduler:
                 target = nxt
             else:                       # fully drained
                 break
-            self.clock.sleep_until(max(target, now))
+            with self._span("sched/wait"):
+                self.clock.sleep_until(max(target, now))
         return self.completed[start:]
 
     def serve(self, requests: Sequence[GraphRequest], *,

@@ -296,21 +296,43 @@ class GraphServeEngine:
         per-wave executor the continuous-batching ``repro.scheduler`` drives;
         ``run()`` keeps the legacy fixed-slicing loop on top of it.
 
-        The whole wave runs inside a ``serve/wave`` span (DESIGN.md §13)
-        tagged with the wave geometry; any kernel-dispatch spans fired at
-        trace time (telemetry on, first wave per geometry) nest inside it."""
-        from repro.observability import TRACER
+        The whole wave runs inside a ``serve/wave`` span (DESIGN.md §13),
+        split into ``serve/assemble`` (validation, slot fill, and the COO
+        build, which copies the adjacency to the device), ``serve/dispatch``
+        (the features' host→device copy, the program's enqueue) and
+        ``serve/fetch`` (the device wait and the logits' copy back); any
+        kernel-dispatch spans fired at trace time (telemetry on, first wave
+        per geometry) nest inside it."""
+        from repro.observability import TRACER, enabled
 
         n = len(wave)
         if n > self.batch:
             raise ValueError(f"wave of {n} requests > {self.batch} slots")
-        with TRACER.span("serve/wave", cat="serve", args={
-                "n_requests": n, "slots": self.batch, "m_pad": self.m_pad,
+        args = {"n_requests": n, "slots": self.batch, "m_pad": self.m_pad,
                 "nnz_pad": self.nnz_pad, "channels": self.cfg.channels,
-                "layer": self.cfg.layer, "impl": self.cfg.impl}):
+                "layer": self.cfg.layer, "impl": self.cfg.impl} \
+            if enabled() else None
+        with TRACER.span("serve/wave", cat="serve", args=args):
             return self._run_wave_inner(wave)
 
     def _run_wave_inner(self, wave: list[GraphRequest]) -> GraphWaveReport:
+        from repro.observability import TRACER
+
+        with TRACER.span("serve/assemble", cat="serve"):
+            x, n_nodes, adj, served, report = self._assemble(wave)
+        with TRACER.span("serve/dispatch", cat="serve"):
+            out = self._dispatch(adj, x, n_nodes)
+        with TRACER.span("serve/fetch", cat="serve"):
+            logits = np.asarray(out)
+        for s, r in served:
+            r.logits = logits[s]
+            r.done = True
+        return report
+
+    def _assemble(self, wave: list[GraphRequest]):
+        """Validate the wave's requests and fill its slots on the host:
+        features, node counts, per-channel padded COO, the served slots and
+        the wave's report."""
         n = len(wave)
         channels = self.cfg.channels
         n_feat = self.cfg.n_features
@@ -344,6 +366,16 @@ class GraphServeEngine:
         adj = [coo_from_lists(t, n_rows=list(n_nodes),
                               nnz_pad=self.nnz_pad)
                for t in triples_by_ch]
+        report = GraphWaveReport(
+            slots=self.batch, n_requests=n, n_failed=n_failed,
+            real_nodes=real_nodes, real_nnz=real_nnz,
+            node_capacity=self.batch * self.m_pad,
+            nnz_capacity=self.batch * channels * self.nnz_pad)
+        return x, n_nodes, adj, served, report
+
+    def _dispatch(self, adj, x, n_nodes):
+        """Copy the wave's operands to the device and enqueue the program;
+        returns its (not yet fetched) logits."""
         adj_arrays = [(a.row_ids, a.col_ids, a.values, a.nnz, a.n_rows)
                       for a in adj]
         x, n_nodes = jnp.asarray(x), jnp.asarray(n_nodes)
@@ -359,15 +391,7 @@ class GraphServeEngine:
 
             adj_arrays, x, n_nodes = jax.tree.map(
                 place, (adj_arrays, x, n_nodes))
-        logits = np.asarray(self._apply(adj_arrays, x, n_nodes))
-        for s, r in served:
-            r.logits = logits[s]
-            r.done = True
-        return GraphWaveReport(
-            slots=self.batch, n_requests=n, n_failed=n_failed,
-            real_nodes=real_nodes, real_nnz=real_nnz,
-            node_capacity=self.batch * self.m_pad,
-            nnz_capacity=self.batch * channels * self.nnz_pad)
+        return self._apply(adj_arrays, x, n_nodes)
 
     # _serve_in_waves drives waves through the same public executor
     _run_wave = run_wave

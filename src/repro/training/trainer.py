@@ -19,6 +19,7 @@ LM ``Trainer`` responsibilities:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -142,12 +143,15 @@ class GCNTrainer:
     the gradient all-reduce over the mesh is inserted by GSPMD from exactly
     that sharded-batch/replicated-params layout.
 
-    Telemetry (DESIGN.md §13): every step records a ``train/step`` span and
-    a wall-time histogram sample on ``registry`` (the process default unless
-    one is passed); loss/accuracy/grad-norm gauges and graphs-throughput
-    sync on the ``tcfg.log_every`` cadence — the per-step path never forces
-    a device sync (JAX async dispatch stays pipelined). ``telemetry=False``
-    opts the instance out entirely.
+    Telemetry (DESIGN.md §13): each iteration of ``fit`` runs in a
+    ``train/iter`` span holding ``train/batch`` (fetch, ELL guard,
+    placement), ``train/step`` (the jitted step's enqueue), ``train/sync``
+    (the ``log_every`` and epoch-end host syncs) and ``train/checkpoint``;
+    steps count on ``registry`` (the process default unless one is passed),
+    and loss/accuracy/grad-norm gauges and graphs-throughput sync on the
+    ``tcfg.log_every`` cadence — the per-step path never forces a device
+    sync (JAX async dispatch stays pipelined). ``telemetry=False`` opts the
+    instance out entirely.
     """
 
     def __init__(self, cfg: GCNConfig, opt: AdamConfig | None = None,
@@ -164,8 +168,6 @@ class GCNTrainer:
         self.telemetry = telemetry
         self.registry = registry if registry is not None else \
             default_registry()
-        self._m_step_s = self.registry.histogram(
-            "train_step_seconds", "per-step wall time (dispatch-paced)")
         self._m_steps = self.registry.counter(
             "train_steps_total", "training steps executed")
         self._m_loss = self.registry.gauge("train_loss", "last synced loss")
@@ -273,7 +275,7 @@ class GCNTrainer:
             raise ValueError("fit_sampled is single-host for now: sampled "
                              "blocks have batch=1, so there is no batch "
                              "axis to shard over a mesh")
-        from repro.observability import TRACER
+        from repro.observability import TRACER, enabled
 
         params, state, start = self.restore_or_init()
         loss = acc = gnorm = float("nan")
@@ -305,13 +307,11 @@ class GCNTrainer:
                               tuple(bl.nnz_pad for bl in b.blocks), impls))
                 if self.telemetry:
                     with TRACER.span("train/sampled_step", cat="train",
-                                     args={"step": seen, **labels_kw}):
-                        t0 = time.perf_counter()
+                                     args={"step": seen, **labels_kw}
+                                     if enabled() else None):
                         params, state, loss, acc, gnorm = self._sampled_step(
                             params, state, adj_arrays, b.x, b.labels,
                             m_pads=m_pads, impls=impls)
-                        self._m_step_s.observe(
-                            time.perf_counter() - t0, **labels_kw)
                     self._m_steps.inc(**labels_kw)
                     m_programs.set(len(programs), **labels_kw)
                     win_nodes += len(b.labels)
@@ -411,6 +411,25 @@ class GCNTrainer:
 
         return jax.tree.map(one, tree)
 
+    def _guard_ell(self, b: dict, memo: dict, candidates: tuple) -> None:
+        """Raise when this batch would reach an ELL impl with a row degree
+        above ``cfg.k_pad`` (see ``fit``). ``memo`` caches, per batch shape,
+        whether any conv layer resolves to one of ``candidates``."""
+        from repro.core.gcn import resolve_conv_impls
+
+        key = (b["x"].shape[0], b["x"].shape[1],
+               max(a.nnz_pad for a in b["adj"]))
+        if key not in memo:
+            memo[key] = (
+                self.cfg.impl in candidates
+                or any(d.impl in candidates
+                       for d in resolve_conv_impls(
+                           self.cfg, *key, itemsize=b["x"].dtype.itemsize,
+                           mesh=self.mesh)))
+        if memo[key]:
+            for a in b["adj"]:
+                validate_ell_k_pad(a, b["x"].shape[1], self.cfg.k_pad)
+
     def fit(self, batch_iter: Iterator[dict] | Callable, *, epochs: int = 1,
             on_metrics: Callable[[int, dict], None] | None = None):
         """``batch_iter``: a callable returning one epoch's batch iterator
@@ -424,7 +443,10 @@ class GCNTrainer:
         (``restore_or_init``) and the first ``start`` batches of the stream
         are fast-forwarded, so a save→kill→restart sequence continues the
         same deterministic trajectory instead of re-initializing at step 0
-        and overwriting the saved state."""
+        and overwriting the saved state.
+
+        Spans (class docstring): one ``train/iter`` per batch taken, and one
+        more per epoch for the fetch that finds the epoch's end."""
         params, state, start = self.restore_or_init()
         if not callable(batch_iter):
             data = (batch_iter if isinstance(batch_iter, (list, tuple))
@@ -448,7 +470,11 @@ class GCNTrainer:
             i for i in IMPLS if precision_of(i)[0] in ("ell", "pallas_ell"))
         maybe_ell = (self.cfg.k_pad is not None
                      and self.cfg.impl in ("auto",) + ell_candidates)
-        from repro.observability import TRACER
+        from repro.observability import TRACER, enabled
+
+        def span(name, args=None):
+            return TRACER.span(name, cat="train", args=args) \
+                if self.telemetry else contextlib.nullcontext()
 
         ell_by_shape: dict[tuple, bool] = {}
         step = seen = 0
@@ -457,66 +483,54 @@ class GCNTrainer:
         log_every = max(self.tcfg.log_every, 1)
         win_t0, win_graphs = time.perf_counter(), 0
         for epoch in range(epochs):
-            for b in batch_iter(epoch):
-                seen += 1
-                if seen <= start:
-                    continue    # already trained before the restart
-                if maybe_ell:
-                    from repro.core.gcn import resolve_conv_impls
-
-                    key = (b["x"].shape[0], b["x"].shape[1],
-                           max(a.nnz_pad for a in b["adj"]))
-                    if key not in ell_by_shape:
-                        ell_by_shape[key] = (
-                            self.cfg.impl in ell_candidates
-                            or any(d.impl in ell_candidates
-                                   for d in resolve_conv_impls(
-                                       self.cfg, *key,
-                                       itemsize=b["x"].dtype.itemsize,
-                                       mesh=self.mesh)))
-                    if ell_by_shape[key]:
-                        for a in b["adj"]:
-                            validate_ell_k_pad(a, b["x"].shape[1],
-                                               self.cfg.k_pad)
-                adj_arrays = [(a.row_ids, a.col_ids, a.values, a.nnz,
-                               a.n_rows) for a in b["adj"]]
-                adj_arrays, x, n_nodes, y = self._place_batch(
-                    (adj_arrays, b["x"], b["n_nodes"], b["labels"]))
-                if self.telemetry:
-                    with TRACER.span("train/step", cat="train",
-                                     args={"step": seen, **labels}):
-                        t0 = time.perf_counter()
+            batches = iter(batch_iter(epoch))
+            while True:
+                with span("train/iter"):
+                    with span("train/batch"):
+                        b = next(batches, None)
+                        if b is None:
+                            break
+                        seen += 1
+                        if seen <= start:
+                            continue    # already trained before the restart
+                        if maybe_ell:
+                            self._guard_ell(b, ell_by_shape, ell_candidates)
+                        adj_arrays = [(a.row_ids, a.col_ids, a.values, a.nnz,
+                                       a.n_rows) for a in b["adj"]]
+                        adj_arrays, x, n_nodes, y = self._place_batch(
+                            (adj_arrays, b["x"], b["n_nodes"], b["labels"]))
+                    with span("train/step", {"step": seen, **labels}
+                              if enabled() else None):
                         params, state, loss, acc, gnorm = self._step(
                             params, state, adj_arrays, x, n_nodes, y)
-                        self._m_step_s.observe(
-                            time.perf_counter() - t0, **labels)
-                    self._m_steps.inc(**labels)
-                    win_graphs += b["x"].shape[0]
-                    if seen % log_every == 0:
-                        # the ONLY per-window device sync (mirrors the LM
-                        # Trainer's log_every posture)
-                        self._m_loss.set(float(loss), **labels)
-                        self._m_acc.set(float(acc), **labels)
-                        self._m_gnorm.set(float(gnorm), **labels)
-                        now = time.perf_counter()
-                        if now > win_t0:
-                            self._m_tput.set(win_graphs / (now - win_t0),
-                                             **labels)
-                        win_t0, win_graphs = now, 0
-                else:
-                    params, state, loss, acc, gnorm = self._step(
-                        params, state, adj_arrays, x, n_nodes, y)
-                step = seen
-                if step % max(self.tcfg.checkpoint_every, 1) == 0:
-                    self.manager.save(step, (params, state))
+                    if self.telemetry:
+                        self._m_steps.inc(**labels)
+                        win_graphs += b["x"].shape[0]
+                        if seen % log_every == 0:
+                            # the ONLY per-window device sync (mirrors the LM
+                            # Trainer's log_every posture)
+                            with span("train/sync"):
+                                self._m_loss.set(float(loss), **labels)
+                                self._m_acc.set(float(acc), **labels)
+                                self._m_gnorm.set(float(gnorm), **labels)
+                            now = time.perf_counter()
+                            if now > win_t0:
+                                self._m_tput.set(
+                                    win_graphs / (now - win_t0), **labels)
+                            win_t0, win_graphs = now, 0
+                    step = seen
+                    if step % max(self.tcfg.checkpoint_every, 1) == 0:
+                        with span("train/checkpoint"):
+                            self.manager.save(step, (params, state))
             if step > start:    # an epoch fully fast-forwarded on resume
+                with span("train/sync"):
+                    rec = {"epoch": epoch + 1, "loss": float(loss),
+                           "acc": float(acc), "grad_norm": float(gnorm),
+                           "time": time.time()}
                 if self.telemetry:
-                    self._m_loss.set(float(loss), **labels)
-                    self._m_acc.set(float(acc), **labels)
-                    self._m_gnorm.set(float(gnorm), **labels)
-                rec = {"epoch": epoch + 1, "loss": float(loss),
-                       "acc": float(acc), "grad_norm": float(gnorm),
-                       "time": time.time()}
+                    self._m_loss.set(rec["loss"], **labels)
+                    self._m_acc.set(rec["acc"], **labels)
+                    self._m_gnorm.set(rec["grad_norm"], **labels)
                 if on_metrics:
                     on_metrics(epoch + 1, rec)
         if step > start:
