@@ -29,6 +29,8 @@ import time
 from typing import Callable, Iterator
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ModelConfig
@@ -128,6 +130,65 @@ class Trainer:
         return params, opt_state
 
 
+class _FlatCarry:
+    """Layout of ``GCNTrainer``'s training carry: the params and Adam's
+    ``m`` and ``v``, each ravelled in the params tree's leaf order, as the
+    three rows of one ``(3, n_params)`` float32 array, plus Adam's int32
+    ``step``. ``pack`` and ``unpack`` trace under ``jit`` and round-trip
+    every value bit for bit (a narrower float param widens to float32 and
+    back)."""
+
+    def __init__(self, params_like):
+        leaves, self.treedef = jax.tree.flatten(params_like)
+        self.shapes = [leaf.shape for leaf in leaves]
+        self.dtypes = [leaf.dtype for leaf in leaves]
+        self.bounds = np.cumsum([0] + [int(np.prod(s)) for s in self.shapes])
+
+    def pack(self, params, state):
+        rows = [jnp.concatenate([jnp.ravel(leaf).astype(jnp.float32)
+                                 for leaf in jax.tree.leaves(tree)])
+                for tree in (params, state["m"], state["v"])]
+        return jnp.stack(rows), state["step"]
+
+    def unpack(self, carry):
+        flat, step = carry
+
+        def tree(row, dtypes):
+            return self.treedef.unflatten([
+                flat[row, lo:hi].reshape(shape).astype(dtype)
+                for lo, hi, shape, dtype in zip(
+                    self.bounds[:-1], self.bounds[1:], self.shapes, dtypes)])
+
+        f32 = [jnp.float32] * len(self.shapes)
+        return tree(0, self.dtypes), {"m": tree(1, f32), "v": tree(2, f32),
+                                      "step": step}
+
+
+class _FlatStep:
+    """``GCNTrainer._step``: calling it runs the jitted step over the flat
+    carry (the program named ``step``, carry donated); ``lower`` takes the
+    pytrees ``init_state`` returns and packs them first, so it lowers the
+    program ``fit`` runs."""
+
+    def __init__(self, jitted, pack):
+        self.jitted, self._pack = jitted, pack
+
+    def __call__(self, carry, adj_arrays, x, n_nodes, labels):
+        return self.jitted(carry, adj_arrays, x, n_nodes, labels)
+
+    def lower(self, params, state, adj_arrays, x, n_nodes, labels):
+        return self.jitted.lower(self._pack(params, state), adj_arrays, x,
+                                 n_nodes, labels)
+
+
+def _read_metrics(metrics) -> tuple[float, float, float]:
+    """Loss, accuracy and gradient norm from the step's ``(3,)`` metrics
+    vector, in one device→host copy (a sync); NaNs before any step."""
+    if metrics is None:
+        return (float("nan"),) * 3
+    return tuple(metrics.tolist())
+
+
 class GCNTrainer:
     """Trainer for the paper's target application: ChemGCN over Batched SpMM.
 
@@ -135,6 +196,22 @@ class GCNTrainer:
     arrays at the jit boundary (the quickstart/test idiom) so retracing is
     shape-keyed only. The SpMM implementation comes from ``cfg.impl`` —
     ``"auto"`` by default, resolved per workload by ``repro.autotune``.
+
+    Calling convention (DESIGN.md §4): inside ``fit`` the training state is
+    one flat carry, ``(flat, step)`` — params, Adam ``m`` and ``v`` ravelled
+    in the params tree's leaf order as the rows of one ``(3, n_params)``
+    float32 array, and Adam's int32 step — packed once after
+    ``restore_or_init`` and donated to every step, which unravels it, runs
+    ``gcn_loss`` → ``value_and_grad`` → ``adam_update`` on pytrees, ravels
+    the result back into the donated buffers and returns loss, accuracy and
+    gradient norm as one ``(3,)`` vector. So the hot call takes the carry's
+    2 buffers and the batch's arrays, returns 3 buffers and allocates no
+    fresh state (``train_step_buffers``: 25 in, 3 out on tox21). The
+    carry is unpacked to ``(params, state)`` pytrees only for a checkpoint,
+    the final save and ``fit``'s return, so checkpoints and return values
+    keep their structure. ``_step.lower(params, state, adj_arrays, x,
+    n_nodes, labels)`` takes ``init_state``'s pytrees, packs them and lowers
+    the program ``fit`` runs (named ``step``).
 
     ``mesh=`` turns the step data-parallel (DESIGN.md §6): every graph
     convolution's Batched SpMM runs mesh-sharded over the ``"data"`` axis
@@ -148,7 +225,9 @@ class GCNTrainer:
     placement), ``train/step`` (the jitted step's enqueue), ``train/sync``
     (the ``log_every`` and epoch-end host syncs) and ``train/checkpoint``;
     steps count on ``registry`` (the process default unless one is passed),
-    and loss/accuracy/grad-norm gauges and graphs-throughput sync on the
+    ``train_step_buffers{dir="in"|"out"}`` holds the argument and result
+    buffer counts of the dispatched step (set once per batch shape), and
+    loss/accuracy/grad-norm gauges and graphs-throughput sync on the
     ``tcfg.log_every`` cadence — the per-step path never forces a device
     sync (JAX async dispatch stays pipelined). ``telemetry=False`` opts the
     instance out entirely.
@@ -177,21 +256,35 @@ class GCNTrainer:
             "train_grad_norm", "last synced global gradient L2 norm")
         self._m_tput = self.registry.gauge(
             "train_graphs_per_s", "graphs/s over the last log window")
+        self._m_buffers = self.registry.gauge(
+            "train_step_buffers",
+            "argument (dir=in) and result (dir=out) buffers of the "
+            "dispatched training step")
 
-        @jax.jit
-        def step(params, state, adj_arrays, x, n_nodes, labels):
+        carry = _FlatCarry(jax.eval_shape(
+            lambda: init_gcn(jax.random.key(0), cfg)))
+        self._pack = jax.jit(carry.pack)
+        self._unpack = jax.jit(carry.unpack)
+
+        @functools.partial(jax.jit, donate_argnums=0)
+        def step(flat_carry, adj_arrays, x, n_nodes, labels):
+            params, state = carry.unpack(flat_carry)
             adj = [BatchedCOO(*a) for a in adj_arrays]
             (loss, acc), grads = jax.value_and_grad(
                 lambda p: gcn_loss(p, self.cfg, adj, x, n_nodes, labels,
                                    mesh=mesh),
                 has_aux=True)(params)
-            gnorm = jax.numpy.sqrt(sum(
-                jax.numpy.vdot(g, g).real
-                for g in jax.tree.leaves(grads)))
+            gnorm = jnp.sqrt(sum(
+                jnp.vdot(g, g).real for g in jax.tree.leaves(grads)))
             params, state = adam_update(self.opt, params, grads, state)
-            return params, state, loss, acc, gnorm
+            new_carry = carry.pack(params, state)
+            if mesh is not None:    # replicated in, replicated out
+                new_carry = jax.lax.with_sharding_constraint(
+                    new_carry, jax.sharding.NamedSharding(
+                        mesh, jax.sharding.PartitionSpec()))
+            return new_carry, jnp.stack([loss, acc, gnorm])
 
-        self._step = step
+        self._step = _FlatStep(step, self._pack_carry)
 
         @functools.partial(jax.jit, static_argnames=("m_pads", "impls"))
         def sampled_step(params, state, adj_arrays, x, labels, *, m_pads,
@@ -377,6 +470,10 @@ class GCNTrainer:
             self.mesh, jax.sharding.PartitionSpec())
         return jax.device_put(tree, repl)
 
+    def _pack_carry(self, params, state):
+        """The flat carry of ``(params, state)``, replicated on the mesh."""
+        return self._replicate(self._pack(params, state))
+
     def init_state(self):
         params = init_gcn(jax.random.key(self.tcfg.seed), self.cfg)
         state = adam_init(params)
@@ -446,13 +543,18 @@ class GCNTrainer:
         and overwriting the saved state.
 
         Spans (class docstring): one ``train/iter`` per batch taken, and one
-        more per epoch for the fetch that finds the epoch's end."""
+        more per epoch for the fetch that finds the epoch's end.
+
+        The state is the flat carry (class docstring) from the pack after
+        ``restore_or_init`` to the unpack for each checkpoint and the
+        return: ``fit`` returns fresh ``(params, state, rec)`` pytrees, of
+        ``init_state``'s structure."""
         params, state, start = self.restore_or_init()
+        carry = self._pack_carry(params, state)
         if not callable(batch_iter):
             data = (batch_iter if isinstance(batch_iter, (list, tuple))
                     else list(batch_iter))
             batch_iter = lambda epoch: data  # noqa: E731
-        loss = acc = float("nan")
         # The jitted step can never data-branch, so the ELL silent-drop
         # guard (ISSUE 5) lives HERE, at the last concrete boundary: when
         # any conv layer's impl resolves to an ELL path for this batch's
@@ -477,8 +579,9 @@ class GCNTrainer:
                 if self.telemetry else contextlib.nullcontext()
 
         ell_by_shape: dict[tuple, bool] = {}
+        step_shapes: set[tuple] = set()
         step = seen = 0
-        gnorm = float("nan")
+        metrics = None
         labels = {"layer": self.cfg.layer, "impl": self.cfg.impl}
         log_every = max(self.tcfg.log_every, 1)
         win_t0, win_graphs = time.perf_counter(), 0
@@ -501,18 +604,28 @@ class GCNTrainer:
                             (adj_arrays, b["x"], b["n_nodes"], b["labels"]))
                     with span("train/step", {"step": seen, **labels}
                               if enabled() else None):
-                        params, state, loss, acc, gnorm = self._step(
-                            params, state, adj_arrays, x, n_nodes, y)
+                        carry, metrics = self._step(
+                            carry, adj_arrays, x, n_nodes, y)
                     if self.telemetry:
                         self._m_steps.inc(**labels)
+                        shape = (x.shape, tuple(a[0].shape
+                                                for a in adj_arrays))
+                        if shape not in step_shapes:
+                            step_shapes.add(shape)
+                            self._m_buffers.set(len(jax.tree.leaves(
+                                (carry, adj_arrays, x, n_nodes, y))),
+                                dir="in")
+                            self._m_buffers.set(len(jax.tree.leaves(
+                                (carry, metrics))), dir="out")
                         win_graphs += b["x"].shape[0]
                         if seen % log_every == 0:
                             # the ONLY per-window device sync (mirrors the LM
                             # Trainer's log_every posture)
                             with span("train/sync"):
-                                self._m_loss.set(float(loss), **labels)
-                                self._m_acc.set(float(acc), **labels)
-                                self._m_gnorm.set(float(gnorm), **labels)
+                                loss, acc, gnorm = _read_metrics(metrics)
+                                self._m_loss.set(loss, **labels)
+                                self._m_acc.set(acc, **labels)
+                                self._m_gnorm.set(gnorm, **labels)
                             now = time.perf_counter()
                             if now > win_t0:
                                 self._m_tput.set(
@@ -521,19 +634,20 @@ class GCNTrainer:
                     step = seen
                     if step % max(self.tcfg.checkpoint_every, 1) == 0:
                         with span("train/checkpoint"):
-                            self.manager.save(step, (params, state))
+                            self.manager.save(step, self._unpack(carry))
             if step > start:    # an epoch fully fast-forwarded on resume
                 with span("train/sync"):
-                    rec = {"epoch": epoch + 1, "loss": float(loss),
-                           "acc": float(acc), "grad_norm": float(gnorm),
-                           "time": time.time()}
+                    loss, acc, gnorm = _read_metrics(metrics)
+                    rec = {"epoch": epoch + 1, "loss": loss, "acc": acc,
+                           "grad_norm": gnorm, "time": time.time()}
                 if self.telemetry:
                     self._m_loss.set(rec["loss"], **labels)
                     self._m_acc.set(rec["acc"], **labels)
                     self._m_gnorm.set(rec["grad_norm"], **labels)
                 if on_metrics:
                     on_metrics(epoch + 1, rec)
+        params, state = self._unpack(carry)
         if step > start:
             self.manager.save(step, (params, state))
-        return params, state, {"loss": float(loss), "acc": float(acc),
-                               "grad_norm": float(gnorm)}
+        loss, acc, gnorm = _read_metrics(metrics)
+        return params, state, {"loss": loss, "acc": acc, "grad_norm": gnorm}

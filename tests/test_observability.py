@@ -752,3 +752,45 @@ def test_compiled_step_names_conv_layers_and_kernels(tmp_path):
         on = _step_hlo(trainer, batch)
     assert on == off        # traced and untraced runs compile the same HLO
     TRACER.clear()
+
+
+def test_step_lowers_the_flat_program_fit_runs(tmp_path):
+    """``_step.lower`` takes ``init_state``'s pytrees, packs them and lowers
+    the program ``fit`` runs: named ``step``, with its ``conv<i>`` and
+    ``spmm/<impl>`` scopes, the carry donated, and the argument and result
+    buffers that ``train_step_buffers`` reports — at most 26 and 4 for
+    ``GCNConfig.tox21()`` at batch 4 (54 and 34 as a pytree step)."""
+    import re
+
+    from repro.core.gcn import GCNConfig
+    from repro.data.graphs import GraphDatasetSpec, batches, generate
+    from repro.training import GCNTrainer, TrainerConfig
+
+    spec = GraphDatasetSpec.tox21_like(n_samples=4, seed=7)
+    batch = next(iter(batches(generate(spec), spec, 4, seed=0)))
+    registry = MetricsRegistry()
+    trainer = GCNTrainer(GCNConfig.tox21(), tcfg=TrainerConfig(
+        checkpoint_dir=str(tmp_path)), registry=registry)
+    params, state = trainer.init_state()
+    adj = [(a.row_ids, a.col_ids, a.values, a.nnz, a.n_rows)
+           for a in batch["adj"]]
+    lowered = trainer._step.lower(params, state, adj, batch["x"],
+                                  batch["n_nodes"], batch["labels"])
+    hlo = lowered.compile().as_text()
+    assert hlo.startswith("HloModule jit_step")
+    ops = re.findall(r'op_name="([^"]*)"', hlo)
+    for layer in ("conv0", "conv1"):
+        assert any(f"({layer})/" in o or f"/{layer}/" in o for o in ops)
+        assert any(re.search(rf"\b{layer}\)?/spmm/\w+/", o) for o in ops)
+    (carry, *batch_args), _ = lowered.args_info
+    assert all(a.donated for a in jax.tree.leaves(carry))
+    assert not any(a.donated for a in jax.tree.leaves(batch_args))
+    n_in = len(jax.tree.leaves(lowered.args_info))
+    n_out = len(jax.tree.leaves(lowered.out_info))
+    assert n_in <= 26 and n_out <= 4, (n_in, n_out)
+
+    trainer.fit([batch])
+    buffers = registry.get("train_step_buffers")
+    assert (buffers.value(dir="in"), buffers.value(dir="out")) == (
+        n_in, n_out)
+    assert trainer._step.jitted._cache_size() == 1

@@ -206,6 +206,41 @@ print("PASS")
     assert "PASS" in r.stdout, r.stdout + "\n" + r.stderr
 
 
+
+def test_gcn_trainer_mesh_fit_keeps_the_carry_replicated():
+    """GCNTrainer(mesh=...).fit: the donated flat carry goes in and comes out
+    replicated, so the step compiles once, and two epochs end where the
+    single-device fit ends (fp tolerance: the grads are all-reduced)."""
+    script = _HEADER + r"""
+import tempfile
+from repro.core.gcn import GCNConfig
+from repro.data.graphs import GraphDatasetSpec, batches, generate
+from repro.training import GCNTrainer, TrainerConfig
+spec = GraphDatasetSpec.tox21_like(n_samples=32, n_features=8, channels=2,
+                                   seed=7)
+bs = list(batches(generate(spec), spec, 16, seed=0))
+cfg = GCNConfig(n_features=8, channels=2, conv_widths=(16,), n_tasks=12,
+                impl="dense")
+out = {}
+for name, mk in (("one", None), ("mesh", mesh)):
+    t = GCNTrainer(cfg, mesh=mk, tcfg=TrainerConfig(
+        checkpoint_dir=tempfile.mkdtemp(), checkpoint_every=3))
+    params, state, rec = t.fit(bs, epochs=2)
+    assert t._step.jitted._cache_size() == 1, name
+    assert int(state["step"]) == 4
+    out[name] = (params, state, rec)
+for leaf in jax.tree.leaves(out["mesh"][:2]):
+    assert leaf.sharding.is_fully_replicated, leaf.sharding
+assert abs(out["one"][2]["loss"] - out["mesh"][2]["loss"]) < 1e-5
+for a, b in zip(jax.tree.leaves(out["one"][:2]),
+                jax.tree.leaves(out["mesh"][:2])):
+    assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+print("PASS")
+"""
+    r = _run(script)
+    assert "PASS" in r.stdout, r.stdout + "\n" + r.stderr
+
+
 def test_sharded_fused_graph_conv_matches_local():
     """Per-shard fused megakernel dispatch (DESIGN.md §7): fwd + all four
     grads match the local fused layer on an 8-way mesh, including a batch

@@ -154,3 +154,89 @@ def test_resume_after_interruption(tmp_path):
     assert 10 in steps and 15 in steps
     # resumed run must not restart from 0: 5 only appears once
     assert steps.count(5) == 1
+
+
+
+_FLAT_CARRY_SCRIPT = r"""
+import shutil, sys
+sys.path.insert(0, sys.argv[1])
+import jax, numpy as np
+from repro.core.formats import BatchedCOO
+from repro.core.gcn import GCNConfig, gcn_loss
+from repro.data.graphs import GraphDatasetSpec, batches, generate
+from repro.optim import adam_update
+from repro.training import GCNTrainer, TrainerConfig
+
+layer, ckdir = sys.argv[2], sys.argv[3]
+spec = GraphDatasetSpec.tox21_like(n_samples=4, n_features=8, channels=2,
+                                   seed=3)
+batch = next(iter(batches(generate(spec), spec, 4, seed=0)))
+cfg = GCNConfig(n_features=8, channels=2, conv_widths=(8, 8), n_tasks=12,
+                layer=layer, heads=2)
+tcfg = TrainerConfig(checkpoint_dir=ckdir, checkpoint_every=2)
+trainer = GCNTrainer(cfg, tcfg=tcfg)
+adj = [(a.row_ids, a.col_ids, a.values, a.nnz, a.n_rows)
+       for a in batch["adj"]]
+
+@jax.jit
+def pytree_step(params, state, adj_arrays, x, n_nodes, labels):
+    coo = [BatchedCOO(*a) for a in adj_arrays]
+    (loss, _), grads = jax.value_and_grad(
+        lambda p: gcn_loss(p, cfg, coo, x, n_nodes, labels),
+        has_aux=True)(params)
+    params, state = adam_update(trainer.opt, params, grads, state)
+    return params, state, loss
+
+params, state = trainer.init_state()
+want, want_losses = [], []
+for _ in range(3):
+    params, state, loss = pytree_step(params, state, adj, batch["x"],
+                                      batch["n_nodes"], batch["labels"])
+    want.append(jax.tree.map(np.asarray, (params, state)))
+    want_losses.append(float(loss))
+
+losses = []
+got_params, got_state, rec = trainer.fit(
+    lambda e: [batch], epochs=3,
+    on_metrics=lambda _, r: losses.append(r["loss"]))
+assert losses == want_losses and rec["loss"] == want_losses[-1], losses
+got = (got_params, got_state)
+assert jax.tree.structure(got) == jax.tree.structure(want[-1])
+for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want[-1])):
+    assert not g.is_deleted()
+    assert g.dtype == w.dtype
+    np.testing.assert_array_equal(np.asarray(g), w)
+assert int(got_state["step"]) == 3
+
+# the step-2 checkpoint was written mid-fit, with the carry still in use
+assert trainer.manager.steps() == [2, 3], trainer.manager.steps()
+shutil.rmtree(trainer.manager._dir(3))
+params2, state2, start = GCNTrainer(cfg, tcfg=tcfg).restore_or_init()
+assert start == 2
+for g, w in zip(jax.tree.leaves((params2, state2)), jax.tree.leaves(want[1])):
+    np.testing.assert_array_equal(np.asarray(g), w)
+print("PASS")
+"""
+
+
+@pytest.mark.parametrize("layer", ["gcn", "gat", "rgcn"])
+def test_gcn_fit_flat_carry_matches_pytree_step(tmp_path, layer):
+    """``fit`` carries params and Adam state as one donated flat buffer; over
+    three steps it matches, bit for bit, the plain pytree step (``gcn_loss``
+    → ``value_and_grad`` → ``adam_update``): params, ``m``, ``v``, ``step``
+    and each step's loss. What it returns is live, and its mid-``fit``
+    checkpoint restores through ``restore_or_init`` to the same values.
+
+    Runs in a process whose CPU backend may not use FMA instructions
+    (``--xla_cpu_max_isa=SSE4_2``): XLA contracts ``a * b + c`` into one
+    FMA wherever a fusion holds both, and the two programs fuse Adam
+    differently, so with FMA some elements of ``m`` differ by an ulp. Without
+    it every operation rounds on its own and the comparison is exact."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": " ".join(filter(None, (
+               os.environ.get("XLA_FLAGS"), "--xla_cpu_max_isa=SSE4_2")))}
+    r = subprocess.run(
+        [sys.executable, "-c", _FLAT_CARRY_SCRIPT, SRC, layer,
+         str(tmp_path / "ck")],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert "PASS" in r.stdout, r.stdout + r.stderr
