@@ -11,8 +11,9 @@ Per corner, three kinds of rows:
 - ``gspmm/<op>_<reduce>/best`` — the fastest impl for the corner with its
   ``ratio=`` speedup over the ``ref`` scatter baseline (≥ 1.0 by
   construction — ref is in the candidate set);
-- ``gspmm/gat_vector/…`` — the GAT aggregation shape (vector edge features,
-  ``(mul, sum)``), the one corner the scalar matrix does not cover.
+- ``gspmm/gat_vector/…`` — vector edge features under ``(mul, sum)`` (the
+  shape GAT's aggregation had before it took scalar attention values), the
+  one corner the scalar matrix does not cover.
 
 ``check_bench_json.py`` additionally requires all 9 (op × reduce) ``best``
 rows to be present — a corner silently dropped from the sweep fails CI.
@@ -74,9 +75,8 @@ def sweep_corner(op: str, reduce: str, coo, m_pad, b, k_pad, *, iters: int):
 
 
 def gat_vector_rows(*, batch, dim, nnz, n_b, iters: int):
-    """The GAT aggregation shape: (mul, sum) with d_e == n_b vector edge
-    features — exercises the vector-edge kernel path the scalar matrix
-    cannot reach."""
+    """(mul, sum) with d_e == n_b vector edge features — exercises the
+    vector-edge kernel path the scalar matrix cannot reach."""
     coo, m_pad, b, k_pad = _inputs(batch, dim, nnz, n_b, d_e=n_b)
     times: dict[str, float] = {}
     for impl in TIMED_IMPLS:

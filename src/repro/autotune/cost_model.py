@@ -145,6 +145,7 @@ def _traffic(policy: str, itemsize: int) -> tuple[int, int, int, int]:
 from repro.analysis.roofline import HW  # noqa: E402
 from repro.core.batching import (  # noqa: E402
     CHUNK,
+    VMEM_BYTES,
     BatchPlan,
     plan_batched_gemm,
     plan_batched_spmm,
@@ -336,7 +337,9 @@ def estimate(w: Workload, impl: str, hw: HW = HW()) -> float:
         return t
 
     if base in ("ell", "pallas_ell"):
-        if w.k_pad is None:
+        if w.k_pad is None or w.m_pad * w.k_pad < w.nnz_pad:
+            # no ELL bound, or fewer ELL slots than the edges padded for:
+            # some row must hold more than k_pad edges
             return float("inf")
         slots = w.batch * w.m_pad * w.k_pad
         flops = 2.0 * slots * w.n_b
@@ -539,6 +542,8 @@ def estimate(w: Workload, impl: str, hw: HW = HW()) -> float:
         if impl == "pallas_gemm":
             plan = plan_batched_gemm(batch=w.batch, m=w.m_pad, n=w.n_b,
                                      k=w.m_pad, itemsize=w.itemsize)
+            if plan.bytes_per_step > VMEM_BYTES:
+                return float("inf")   # the whole A block must sit in VMEM
             t += w.batch * plan.p * GRID_STEP_OVERHEAD
         return t
 

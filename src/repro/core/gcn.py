@@ -7,6 +7,11 @@ dense prediction head. Two task heads match the evaluation datasets:
 - Tox21: 12 independent binary toxicity tasks (sigmoid + BCE);
 - Reaction100: 100-way reaction classification (softmax + CE).
 
+``GCNConfig.ppi_gat()`` is the node-level counterpart: GAT on PPI-shaped
+protein graphs (arXiv:1710.10903 §3.3), ELU and no batch norm, a skip
+projection across the middle layer, and the last layer's head mean as each
+node's 121 label logits under a per-node sigmoid cross-entropy.
+
 The model is pure-functional (init/apply), with ``batched=True`` selecting the
 Fig. 7 execution and ``batched=False`` the Fig. 6 baseline — identical
 numerics, different op structure. Conv layer ``i`` is traced under
@@ -35,7 +40,13 @@ class GCNConfig:
     channels: int = 4             # bond-type adjacency channels
     conv_widths: tuple[int, ...] = (64, 64)   # Tox21: two layers of 64
     n_tasks: int = 12             # Tox21: 12 binary tasks
-    task: str = "multitask_binary"  # or "multiclass"
+    task: str = "multitask_binary"  # graph level: "multitask_binary" |
+                                  # "multiclass" (masked sum readout, then
+                                  # a dense head); node level:
+                                  # "node_multilabel" (the last conv layer's
+                                  # head mean IS each node's logits: no
+                                  # readout, no head; its width / heads
+                                  # must equal n_tasks)
     layer: str = "gcn"            # conv layer kind (DESIGN.md §11):
                                   # "gcn"  — channel-summed graph conv
                                   #          (paper eq. (2));
@@ -44,8 +55,14 @@ class GCNConfig:
                                   #          connectivity (models/gnn.py);
                                   # "rgcn" — adjacency channels as relations
                                   #          with per-relation weights
-    heads: int = 4                # attention heads (layer="gat" only; every
-                                  # conv width must divide by it)
+    heads: int | tuple[int, ...] = 4  # attention heads (layer="gat" only):
+                                  # one for every layer, or one per layer;
+                                  # each conv width must divide by its
+                                  # layer's heads (concatenated heads, or
+                                  # averaged in a node task's last layer)
+    activation: str = "relu"      # after every hidden layer: "relu" | "elu"
+    skip: tuple[int, ...] = ()    # gat layers that add a learned projection
+                                  # of their input before the activation
     impl: str = "auto"            # layer implementation (repro.core.spmm.IMPLS
                                   # incl. the "fused" megakernel; "auto" =
                                   # adaptive dispatch, DESIGN.md §5/§7)
@@ -63,7 +80,8 @@ class GCNConfig:
                                   # wave-composition-INVARIANT, required for
                                   # continuous-batching serving where the set
                                   # of co-batched requests is a scheduling
-                                  # accident (DESIGN.md §8)
+                                  # accident (DESIGN.md §8); "none": no
+                                  # batch norm (and no bn params)
 
     @staticmethod
     def tox21(**kw) -> "GCNConfig":
@@ -76,32 +94,70 @@ class GCNConfig:
         return GCNConfig(conv_widths=(512, 512, 512), n_tasks=100,
                          task="multiclass", **kw)
 
+    @staticmethod
+    def ppi_gat(**kw) -> "GCNConfig":
+        """GAT on PPI (arXiv:1710.10903 §3.3): three attention layers, 4
+        heads of 256 concatenated twice with ELU and a skip projection
+        across the middle layer, then 6 heads of 121 averaged into each
+        node's 121 label logits; no batch norm. ``kw`` overrides any
+        field (a test's smaller widths)."""
+        return dataclasses.replace(
+            GCNConfig(n_features=50, channels=1,
+                      conv_widths=(1024, 1024, 726), n_tasks=121,
+                      task="node_multilabel", layer="gat", heads=(4, 4, 6),
+                      activation="elu", skip=(1,), bn_mode="none"), **kw)
 
-def _init_conv(key, cfg: GCNConfig, n_in: int, n_out: int):
-    """One conv layer's params for ``cfg.layer`` (DESIGN.md §11)."""
+    @property
+    def node_task(self) -> bool:
+        return self.task == "node_multilabel"
+
+    def layer_heads(self, i: int) -> int:
+        """Attention heads of conv layer ``i``."""
+        return self.heads[i] if isinstance(self.heads, tuple) else self.heads
+
+
+def _init_conv(key, cfg: GCNConfig, i: int, n_in: int, n_out: int):
+    """Conv layer ``i``'s params for ``cfg.layer`` (DESIGN.md §11)."""
+    if cfg.skip and cfg.layer != "gat":
+        raise ValueError("skip projections are a gat-layer option; "
+                         f"layer={cfg.layer!r}")
     if cfg.layer == "gcn":
         return init_graph_conv(key, n_in, n_out, cfg.channels)
     from repro.models.gnn import init_gat_layer, init_rgcn_layer
 
     if cfg.layer == "gat":
-        return init_gat_layer(key, n_in, n_out, cfg.heads)
+        return init_gat_layer(key, n_in, n_out, cfg.layer_heads(i),
+                              skip=i in cfg.skip)
     if cfg.layer == "rgcn":
         return init_rgcn_layer(key, n_in, n_out, cfg.channels)
     raise ValueError(f"unknown layer kind {cfg.layer!r}: expected 'gcn', "
                      "'gat' or 'rgcn'")
 
 
+def _n_hidden(cfg: GCNConfig) -> int:
+    """Conv layers followed by batch norm and the activation: all of them,
+    but a node task's last layer, whose output is the logits."""
+    return len(cfg.conv_widths) - cfg.node_task
+
+
 def init_gcn(key, cfg: GCNConfig):
+    """``{"convs", "bns", "head"}``; a config without batch norm has no
+    ``bns`` and a node task no ``head``."""
     keys = jax.random.split(key, len(cfg.conv_widths) + 1)
     params = {"convs": [], "bns": []}
     n_in = cfg.n_features
     for i, w in enumerate(cfg.conv_widths):
-        params["convs"].append(_init_conv(keys[i], cfg, n_in, w))
-        params["bns"].append({
-            "scale": jnp.ones((w,), jnp.float32),
-            "bias": jnp.zeros((w,), jnp.float32),
-        })
+        params["convs"].append(_init_conv(keys[i], cfg, i, n_in, w))
+        if i < _n_hidden(cfg):
+            params["bns"].append({
+                "scale": jnp.ones((w,), jnp.float32),
+                "bias": jnp.zeros((w,), jnp.float32),
+            })
         n_in = w
+    if cfg.bn_mode == "none":
+        del params["bns"]
+    if cfg.node_task:
+        return params
     scale = 1.0 / jnp.sqrt(n_in)
     params["head"] = {
         "w": jax.random.uniform(keys[-1], (n_in, cfg.n_tasks), jnp.float32,
@@ -127,10 +183,10 @@ def resolve_conv_impls(cfg: GCNConfig, batch: int, m_pad: int, nnz_pad: int,
 
     ``cfg.layer`` selects the workload shape (DESIGN.md §11): ``"gcn"``
     resolves the graph-conv LAYER workload (fused megakernel vs stacked
-    SpMM); ``"gat"`` resolves the attention aggregation's vector-edge
-    ``(mul, sum)`` g-SpMM over the head-flattened batch; ``"rgcn"`` the
-    ``(copy_lhs, mean)`` g-SpMM over the relation-flattened batch — both
-    over the g-SpMM-capable candidate subset."""
+    SpMM); ``"gat"`` resolves the attention aggregation, a plain SpMM with
+    the attention weights as scalar edge values over the head-flattened
+    batch (the full ladder); ``"rgcn"`` the ``(copy_lhs, mean)`` g-SpMM over
+    the relation-flattened batch (the g-SpMM-capable candidate subset)."""
     from repro import autotune
     from repro.kernels import resolve_interpret
 
@@ -139,13 +195,13 @@ def resolve_conv_impls(cfg: GCNConfig, batch: int, m_pad: int, nnz_pad: int,
     n_in = cfg.n_features
     dtype = (autotune.precision_of(cfg.impl)[1] if cfg.impl != "auto"
              else cfg.precision)
-    for n_out in cfg.conv_widths:
+    for i, n_out in enumerate(cfg.conv_widths):
         if cfg.layer == "gat":
-            d_head = n_out // cfg.heads
+            heads = cfg.layer_heads(i)
             w = autotune.Workload(
-                batch=batch * cfg.heads, m_pad=m_pad, nnz_pad=nnz_pad,
-                k_pad=cfg.k_pad, n_b=d_head, itemsize=itemsize,
-                dtype=dtype, d_e=d_head)
+                batch=batch * heads, m_pad=m_pad, nnz_pad=nnz_pad,
+                k_pad=cfg.k_pad, n_b=n_out // heads, itemsize=itemsize,
+                dtype=dtype)
         elif cfg.layer == "rgcn":
             w = autotune.Workload(
                 batch=batch * cfg.channels, m_pad=m_pad, nnz_pad=nnz_pad,
@@ -201,13 +257,14 @@ def _batch_norm(p, x, mask, mode: str = "batch"):
     return xn * p["scale"] + p["bias"]
 
 
-def _conv(conv_p, cfg: GCNConfig, adj, h, mesh):
+def _conv(conv_p, cfg: GCNConfig, adj, h, mesh, *, mean_heads=False):
     """One conv layer of kind ``cfg.layer`` (DESIGN.md §11)."""
     if cfg.layer == "gat":
         from repro.models.gnn import gat_layer
 
         return gat_layer(conv_p, adj[0], h, impl=cfg.impl, k_pad=cfg.k_pad,
-                         interpret=cfg.interpret, mesh=mesh)
+                         interpret=cfg.interpret, mesh=mesh,
+                         mean_heads=mean_heads)
     if cfg.layer == "rgcn":
         from repro.models.gnn import rgcn_layer
 
@@ -236,12 +293,17 @@ def apply_gcn(
         # GAT/R-GCN only exist on the batched g-SpMM stack — there is no
         # Fig. 6 per-sample baseline for them
         raise ValueError(f"layer={cfg.layer!r} requires batched=True")
+    act = {"relu": jax.nn.relu, "elu": jax.nn.elu}[cfg.activation]
     h = x
-    for i, (conv_p, bn_p) in enumerate(zip(params["convs"], params["bns"])):
+    for i, conv_p in enumerate(params["convs"]):
+        last = i == _n_hidden(cfg)
         with jax.named_scope(f"conv{i}"):
-            h = _conv(conv_p, cfg, adj, h, mesh)
-        h = _batch_norm(bn_p, h * mask, mask, cfg.bn_mode)
-        h = jax.nn.relu(h) * mask
+            h = _conv(conv_p, cfg, adj, h, mesh, mean_heads=last)
+        if last:                        # a node task's per-node logits
+            return h
+        if cfg.bn_mode != "none":
+            h = _batch_norm(params["bns"][i], h * mask, mask, cfg.bn_mode)
+        h = act(h) * mask
     readout = jnp.sum(h, axis=1)                          # masked sum readout
     return readout @ params["head"]["w"] + params["head"]["b"]
 
@@ -318,7 +380,18 @@ def gcn_node_loss(params, cfg: GCNConfig, adjs, x, labels, *,
 
 def gcn_loss(params, cfg: GCNConfig, adj, x, n_nodes, labels, *, mesh=None):
     logits = apply_gcn(params, cfg, adj, x, n_nodes, mesh=mesh)
-    if cfg.task == "multitask_binary":
+    if cfg.node_task:
+        # labels: (batch, m_pad, n_tasks) in {0, 1}; the mean sigmoid
+        # cross-entropy over real nodes × labels
+        z = logits
+        real = (jnp.arange(z.shape[1])[None, :] < n_nodes[:, None])
+        real = real.astype(z.dtype)[..., None]
+        count = jnp.maximum(jnp.sum(real), 1.0) * z.shape[-1]
+        per = jnp.maximum(z, 0) - z * labels + jnp.log1p(jnp.exp(-jnp.abs(z)))
+        loss = jnp.sum(per * real) / count
+        hit = ((z > 0).astype(jnp.float32) == labels).astype(jnp.float32)
+        acc = jnp.sum(hit * real) / count
+    elif cfg.task == "multitask_binary":
         # labels: (batch, n_tasks) in {0, 1}
         z = logits
         loss = jnp.maximum(z, 0) - z * labels + jnp.log1p(jnp.exp(-jnp.abs(z)))

@@ -1,4 +1,6 @@
-"""Synthetic molecular-graph datasets shaped like the paper's (Table I).
+"""Synthetic graph datasets: molecules shaped like the paper's (Table I),
+PPI-shaped protein graphs (``ppi_like``) and one giant graph for the
+sampled tier (``reddit_like``).
 
 Tox21 and Reaction100 are not redistributable here, so we generate graphs with
 the same statistics the paper reports — max dim 50 nodes, bond-degree ≤ 4,
@@ -130,6 +132,93 @@ def generate(spec: GraphDatasetSpec) -> list[GraphSample]:
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class PPISpec:
+    """Protein–protein-interaction graphs at the published totals of PPI
+    (Zitnik & Leskovec 2017, as tabled in arXiv:1710.10903 Table 1): 20
+    training graphs of 44,906 nodes in all, 28.8 directed edges per node
+    on average, 50 features and 121 labels per node. The spread of graph
+    sizes, the degree exponent and the label density are assumptions: the
+    paper gives none of them."""
+
+    n_graphs: int = 20
+    total_nodes: int = 44_906
+    min_nodes: int = 590           # assumed
+    max_nodes: int = 3_480         # assumed
+    avg_degree: float = 28.8       # directed edges per node, no self loops
+    degree_alpha: float = 0.5      # assumed: power-law exponent
+    n_features: int = 50
+    n_labels: int = 121
+    label_density: float = 0.3     # assumed
+    seed: int = 0
+
+    def dataset_spec(self) -> GraphDatasetSpec:
+        """The ``GraphDatasetSpec`` that ``batches`` reads: one channel."""
+        return GraphDatasetSpec(
+            n_samples=self.n_graphs, max_nodes=self.max_nodes,
+            min_nodes=self.min_nodes, channels=1,
+            n_features=self.n_features, n_tasks=self.n_labels,
+            task="node_multilabel", seed=self.seed)
+
+
+def _ppi_sizes(rng: np.random.Generator, spec: PPISpec) -> np.ndarray:
+    """Node counts of the graphs: one at each end of the range, the rest
+    drawn uniformly and scaled so that all of them sum to the total."""
+    n = spec.n_graphs
+    rest = spec.total_nodes - spec.min_nodes - spec.max_nodes
+    u = rng.uniform(spec.min_nodes, spec.max_nodes, n - 2)
+    sizes = np.clip(np.rint(u * rest / u.sum()), spec.min_nodes,
+                    spec.max_nodes).astype(np.int64)
+    while sizes.sum() != rest:                  # rounding and clipping
+        room = (sizes < spec.max_nodes if sizes.sum() < rest
+                else sizes > spec.min_nodes)
+        i = rng.choice(np.flatnonzero(room))
+        sizes[i] += 1 if sizes.sum() < rest else -1
+    out = np.concatenate([[spec.min_nodes, spec.max_nodes], sizes])
+    return rng.permutation(out)
+
+
+def _ppi_graph(rng: np.random.Generator, n: int, spec: PPISpec, w_teach):
+    """One graph: Chung–Lu edges on power-law weights, made symmetric and
+    without duplicates, ``round(avg_degree · n)`` directed edges, then a
+    self loop on every node; features, and labels from a one-hop teacher
+    thresholded per label at ``label_density``."""
+    weights = powerlaw_degrees(rng, n, spec.avg_degree, spec.degree_alpha)
+    # + 1: the tail's weights round to 0, and every node should have edges
+    p = (weights + 1.0) / (weights + 1.0).sum()
+    want = int(round(spec.avg_degree * n / 2))   # undirected pairs
+    pairs = np.zeros((0,), np.int64)
+    while len(pairs) < want:
+        u, v = rng.choice(n, (2, 2 * want), p=p)
+        keep = u != v
+        key = np.minimum(u, v)[keep] * n + np.maximum(u, v)[keep]
+        pairs = np.concatenate([pairs, key])
+        _, first = np.unique(pairs, return_index=True)
+        pairs = pairs[np.sort(first)]           # first draws win, in order
+    pairs = pairs[:want]
+    a, b = pairs // n, pairs % n
+    loops = np.arange(n)
+    rows = np.concatenate([a, b, loops]).astype(np.int32)
+    cols = np.concatenate([b, a, loops]).astype(np.int32)
+    feats = rng.standard_normal((n, spec.n_features)).astype(np.float32)
+    agg = np.zeros_like(feats)
+    np.add.at(agg, rows, feats[cols])
+    agg /= np.bincount(rows, minlength=n)[:, None]
+    logits = agg @ w_teach
+    cut = np.quantile(logits, 1.0 - spec.label_density, axis=0)
+    labels = (logits > cut).astype(np.float32)
+    return GraphSample([rows], [cols], n, feats, labels)
+
+
+def ppi_like(spec: PPISpec = PPISpec()) -> list[GraphSample]:
+    """The PPI-shaped training graphs of ``spec``, a pure function of
+    ``spec.seed``. Each sample's ``label`` is ``(n_nodes, n_labels)``."""
+    rng = np.random.default_rng(spec.seed)
+    w_teach = rng.standard_normal((spec.n_features, spec.n_labels))
+    return [_ppi_graph(rng, int(n), spec, w_teach)
+            for n in _ppi_sizes(rng, spec)]
+
+
 def batches(
     data: list[GraphSample],
     spec: GraphDatasetSpec,
@@ -143,7 +232,10 @@ def batches(
     start_epoch: int = 0,
 ) -> Iterator[dict]:
     """Padding batch iterator: pads every sample to the dataset max (static
-    shapes → one compiled step), yields per-channel BatchedCOO + features.
+    shapes → one compiled step), yields per-channel BatchedCOO + features,
+    labels (a node task's padded to ``m_pad`` rows), and as host integers
+    ``edges``, the real edges over every sample and channel, and
+    ``edge_slots``, the padded slots that hold them.
 
     Each epoch's shuffle is a pure function of ``(seed, epoch)`` — NOT one
     sequentially-consumed RNG — so a checkpoint-restored run can rebuild any
@@ -177,13 +269,22 @@ def batches(
             feats = np.zeros((len(samples), m_pad, spec.n_features), np.float32)
             for k, s in enumerate(samples):
                 feats[k, :s.n_nodes] = s.features
-            labels = np.stack([s.label for s in samples])
+            if spec.task == "node_multilabel":
+                labels = np.zeros((len(samples), m_pad, spec.n_tasks),
+                                  np.float32)
+                for k, s in enumerate(samples):
+                    labels[k, :s.n_nodes] = s.label
+            else:
+                labels = np.stack([s.label for s in samples])
             yield {
                 "adj": adj,
                 "x": jnp.asarray(feats),
                 "n_nodes": jnp.asarray([s.n_nodes for s in samples],
                                        jnp.int32),
                 "labels": jnp.asarray(labels),
+                "edges": sum(len(s.rows[ch]) for s in samples
+                             for ch in range(spec.channels)),
+                "edge_slots": len(samples) * spec.channels * nnz_pad,
             }
 
 

@@ -9,9 +9,11 @@ per-head loop:
   transform is one einsum; per-edge attention logits are two gathers over
   node-level projections; the softmax over each destination row's incoming
   edges is :func:`repro.kernels.segment_softmax.segment_softmax`; and the
-  attention-weighted aggregation of EVERY head is ONE vector-edge
-  ``(mul, sum)`` g-SpMM with the head axis flattened into the batch axis —
-  the attention weights are the edge-feature vectors.
+  attention-weighted aggregation of EVERY head is ONE ``batched_spmm`` with
+  the head axis flattened into the batch axis — each head's attention
+  weights are its scalar edge values. An optional skip projection and a
+  mean over the heads (a node task's output layer) complete the PPI
+  configuration of the paper.
 - ``rgcn_layer`` (Relational GCN, arXiv:1703.06103): the per-relation weight
   transforms run as ONE ragged :func:`repro.kernels.grouped_matmul` over
   relation-major tokens (the MoE idiom of DESIGN.md §4 — relations are the
@@ -30,28 +32,35 @@ from repro.core.formats import BatchedCOO
 from repro.core.graph_conv import flatten_channels
 from repro.core.message_passing import message_passing
 from repro.kernels.grouped_matmul import grouped_matmul
+from repro.kernels.ops import batched_spmm
 from repro.kernels.segment_softmax import segment_softmax
 
 
-def init_gat_layer(key, n_in: int, n_out: int, heads: int):
+def init_gat_layer(key, n_in: int, n_out: int, heads: int, *,
+                   skip: bool = False):
     """Multi-head GAT parameters: per-head transform ``w`` to ``n_out //
     heads`` features, split attention vectors ``a_src``/``a_dst`` (the
-    concatenation trick: a·[h_i ‖ h_j] = a_src·h_j + a_dst·h_i), and an
-    output bias over the concatenated heads."""
+    concatenation trick: a·[h_i ‖ h_j] = a_src·h_j + a_dst·h_i), an
+    output bias over the concatenated heads, and with ``skip`` the skip
+    projection ``w_skip`` of the layer's input to every head's width."""
     if n_out % heads:
         raise ValueError(f"n_out={n_out} not divisible by heads={heads}")
     d_head = n_out // heads
-    k1, k2, k3 = jax.random.split(key, 3)
+    keys = jax.random.split(key, 4 if skip else 3)
     scale = 1.0 / jnp.sqrt(n_in)
-    return {
-        "w": jax.random.uniform(k1, (heads, n_in, d_head), jnp.float32,
+    params = {
+        "w": jax.random.uniform(keys[0], (heads, n_in, d_head), jnp.float32,
                                 -scale, scale),
-        "a_src": jax.random.uniform(k2, (heads, d_head), jnp.float32,
+        "a_src": jax.random.uniform(keys[1], (heads, d_head), jnp.float32,
                                     -scale, scale),
-        "a_dst": jax.random.uniform(k3, (heads, d_head), jnp.float32,
+        "a_dst": jax.random.uniform(keys[2], (heads, d_head), jnp.float32,
                                     -scale, scale),
         "b": jnp.zeros((n_out,), jnp.float32),
     }
+    if skip:
+        params["w_skip"] = jax.random.uniform(
+            keys[3], (n_in, n_out), jnp.float32, -scale, scale)
+    return params
 
 
 def gat_layer(
@@ -64,56 +73,68 @@ def gat_layer(
     interpret: bool | None = None,
     mesh=None,
     negative_slope: float = 0.2,
+    mean_heads: bool = False,
 ) -> jax.Array:
     """One multi-head graph-attention layer → ``(batch, m_pad, n_out)`` with
-    the heads' outputs concatenated.
+    the heads' outputs concatenated, or ``(batch, m_pad, n_out // heads)``
+    averaged over the heads with ``mean_heads``.
 
     ``alpha = segment_softmax(LeakyReLU(a_src·h[cid] + a_dst·h[rid]))`` per
     head over each destination row's incoming edges, then the aggregation
     ``out[r] = Σ_edges alpha · h[cid]`` for ALL heads runs as ONE
-    ``(mul, sum)`` g-SpMM: heads flatten into the batch axis (head-major)
-    and the per-edge ``alpha`` broadcasts across the head width as a
-    vector edge feature. Zero-degree rows get all-zero attention rows from
-    ``segment_softmax`` and therefore the 0.0 identity output with finite
-    (zero) gradients — no NaN from the empty softmax.
-    """
+    ``batched_spmm``: heads flatten into the batch axis (head-major) and
+    each head's ``alpha`` is the scalar value of its edges, so ``impl``
+    resolves over the full SpMM ladder. Zero-degree rows get all-zero
+    attention rows from ``segment_softmax`` and therefore the 0.0 output
+    with finite (zero) gradients — no NaN from the empty softmax. With
+    ``w_skip`` in ``params`` the projection ``x @ w_skip`` is added to the
+    concatenated heads (before any mean and the caller's activation).
+
+    Traced under the name scopes ``gat/project``, ``gat/logits``,
+    ``gat/softmax`` and ``gat/aggregate`` (the SpMM's ``spmm/<impl>``
+    inside the last)."""
     heads, _, d_head = params["w"].shape
     batch, m_pad, _ = x.shape
     nnz_pad = adj.row_ids.shape[1]
 
-    h = jnp.einsum("bmn,hnf->hbmf", x, params["w"])    # (heads, b, m, d_head)
-    # node-level halves of the edge logit, then two gathers per edge
-    s_src = jnp.einsum("hbmf,hf->hbm", h, params["a_src"])
-    s_dst = jnp.einsum("hbmf,hf->hbm", h, params["a_dst"])
-    gather = jax.vmap(jax.vmap(lambda s, ids: s[ids]))  # over (heads, batch)
-    logits = (gather(s_src, jnp.broadcast_to(adj.col_ids, (heads, batch,
-                                                           nnz_pad)))
-              + gather(s_dst, jnp.broadcast_to(adj.row_ids, (heads, batch,
-                                                             nnz_pad))))
-    logits = jax.nn.leaky_relu(logits, negative_slope)
-    # per-row softmax, independent per head: (batch, nnz_pad, heads)
-    alpha = segment_softmax(logits.transpose(1, 2, 0), adj.row_ids,
-                            nnz=adj.nnz, m_pad=m_pad)
+    with jax.named_scope("gat/project"):
+        h = jnp.einsum("bmn,hnf->hbmf", x, params["w"])  # (heads, b, m, d)
+        # node-level halves of the edge logit
+        s_src = jnp.einsum("hbmf,hf->hbm", h, params["a_src"])
+        s_dst = jnp.einsum("hbmf,hf->hbm", h, params["a_dst"])
+    with jax.named_scope("gat/logits"):
+        gather = jax.vmap(jax.vmap(lambda s, ids: s[ids]))  # (heads, batch)
+        ids = (jnp.broadcast_to(adj.col_ids, (heads, batch, nnz_pad)),
+               jnp.broadcast_to(adj.row_ids, (heads, batch, nnz_pad)))
+        logits = jax.nn.leaky_relu(
+            gather(s_src, ids[0]) + gather(s_dst, ids[1]), negative_slope)
+    with jax.named_scope("gat/softmax"):
+        # per-row softmax, independent per head: (batch, nnz_pad, heads)
+        alpha = segment_softmax(logits.transpose(1, 2, 0), adj.row_ids,
+                                nnz=adj.nnz, m_pad=m_pad)
 
-    # ONE aggregation for all heads: flatten heads into the batch axis
-    # (head-major, like graph_conv's flatten_channels) and carry alpha as a
-    # vector edge feature broadcast over the head width
-    def flat(t):
-        return jnp.broadcast_to(t, (heads,) + t.shape).reshape(
-            (heads * batch,) + t.shape[1:])
+    with jax.named_scope("gat/aggregate"):
+        # ONE SpMM for all heads: heads flatten into the batch axis
+        # (head-major, like graph_conv's flatten_channels) with each head's
+        # alpha as its scalar edge values
+        def flat(t):
+            return jnp.broadcast_to(t, (heads,) + t.shape).reshape(
+                (heads * batch,) + t.shape[1:])
 
-    e_vec = jnp.repeat(
-        alpha.transpose(2, 0, 1).reshape(heads * batch, nnz_pad)[..., None],
-        d_head, axis=-1)
-    a_flat = BatchedCOO(row_ids=flat(adj.row_ids), col_ids=flat(adj.col_ids),
-                        values=e_vec, nnz=flat(adj.nnz),
-                        n_rows=flat(adj.n_rows))
-    out = message_passing(a_flat, h.reshape(heads * batch, m_pad, d_head),
-                          op="mul", reduce="sum", impl=impl, k_pad=k_pad,
-                          interpret=interpret, mesh=mesh)
-    out = out.reshape(heads, batch, m_pad, d_head)
-    return (out.transpose(1, 2, 0, 3).reshape(batch, m_pad, heads * d_head)
-            + params["b"])
+        a_flat = BatchedCOO(
+            row_ids=flat(adj.row_ids), col_ids=flat(adj.col_ids),
+            values=alpha.transpose(2, 0, 1).reshape(heads * batch, nnz_pad),
+            nnz=flat(adj.nnz), n_rows=flat(adj.n_rows))
+        out = batched_spmm(a_flat, h.reshape(heads * batch, m_pad, d_head),
+                           impl=impl, k_pad=k_pad, interpret=interpret,
+                           mesh=mesh)
+        out = (out.reshape(heads, batch, m_pad, d_head).transpose(1, 2, 0, 3)
+               .reshape(batch, m_pad, heads * d_head) + params["b"])
+        if "w_skip" in params:
+            out = out + x @ params["w_skip"]
+        if mean_heads:
+            out = jnp.mean(out.reshape(batch, m_pad, heads, d_head), axis=2)
+    return out
 
 
 def init_rgcn_layer(key, n_in: int, n_out: int, relations: int):

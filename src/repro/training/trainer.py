@@ -225,6 +225,9 @@ class GCNTrainer:
     placement), ``train/step`` (the jitted step's enqueue), ``train/sync``
     (the ``log_every`` and epoch-end host syncs) and ``train/checkpoint``;
     steps count on ``registry`` (the process default unless one is passed),
+    and so do the real edges and padded edge slots of each batch that
+    carries them as host integers (``train_edges_total``,
+    ``train_edge_slots_total``; ``data.graphs.batches`` does),
     ``train_step_buffers{dir="in"|"out"}`` holds the argument and result
     buffer counts of the dispatched step (set once per batch shape), and
     loss/accuracy/grad-norm gauges and graphs-throughput sync on the
@@ -260,6 +263,11 @@ class GCNTrainer:
             "train_step_buffers",
             "argument (dir=in) and result (dir=out) buffers of the "
             "dispatched training step")
+        self._m_edges = self.registry.counter(
+            "train_edges_total", "real edges of the batches stepped on")
+        self._m_edge_slots = self.registry.counter(
+            "train_edge_slots_total",
+            "padded edge slots of the batches stepped on")
 
         carry = _FlatCarry(jax.eval_shape(
             lambda: init_gcn(jax.random.key(0), cfg)))
@@ -608,6 +616,9 @@ class GCNTrainer:
                             carry, adj_arrays, x, n_nodes, y)
                     if self.telemetry:
                         self._m_steps.inc(**labels)
+                        if "edges" in b:    # host ints of the batch builder
+                            self._m_edges.inc(b["edges"], **labels)
+                            self._m_edge_slots.inc(b["edge_slots"], **labels)
                         shape = (x.shape, tuple(a[0].shape
                                                 for a in adj_arrays))
                         if shape not in step_shapes:
