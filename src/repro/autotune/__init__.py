@@ -4,7 +4,8 @@ Makes ``impl="auto"`` a first-class value in ``repro.core.spmm.batched_spmm``:
 
 - :mod:`repro.autotune.cost_model` — shape-keyed analytic ranking of the
   implementations (roofline terms + dispatch overheads over the planner's
-  case analysis);
+  case analysis), and the HBM rule that picks a GAT layer's attention path
+  (``gat_attention_path``);
 - :mod:`repro.autotune.selector` — the Decision object and precedence rules
   (case-3 force → tuning-cache winner → model winner);
 - :mod:`repro.autotune.cache` — persistent JSON cache of on-device
@@ -24,6 +25,7 @@ from repro.autotune.cost_model import (  # noqa: F401
     Workload,
     estimate,
     estimate_layer,
+    gat_attention_path,
     precision_of,
     rank,
     rank_layer,
@@ -43,7 +45,7 @@ from repro.autotune.selector import (  # noqa: F401
 __all__ = [
     "ENV_VAR", "TuningCache", "autotune", "default_cache", "measure_workload",
     "GSPMM_IMPLS", "PRECISION_IMPLS", "Workload", "estimate",
-    "estimate_layer", "precision_of", "rank", "rank_layer", "spmm_plan",
-    "supports_gspmm", "KINDS", "Decision", "forced_decision", "resolve_auto",
+    "estimate_layer", "gat_attention_path", "precision_of", "rank",
+    "rank_layer", "spmm_plan", "supports_gspmm", "KINDS", "Decision", "forced_decision", "resolve_auto",
     "select_graph_conv_impl", "select_impl",
 ]

@@ -648,3 +648,38 @@ def rank_layer(w: Workload, *, allow_pallas: bool = True,
     scored = [(i, estimate_layer(w, i, hw)) for i in candidates]
     scored = [(i, t) for i, t in scored if t != float("inf")]
     return tuple(sorted(scored, key=lambda it: it[1]))
+
+
+# GAT attention (models/gnn.py): a dense f32 (heads·batch, m_pad, m_pad)
+# block per layer may take this share of the device's HBM. Below it the
+# logits, softmax and aggregation run as dense blocks (an HBM stream and an
+# MXU matmul), which on the v5e beat XLA's per-edge gathers and scatters
+# (10-17 ms per 8 × 103,704 slots) down to about 4e-4 edges per node pair,
+# so no density test is needed at sizes that fit.
+GAT_BLOCK_HBM_SHARE = 1 / 16
+V5E_HBM_BYTES = 16 * 2 ** 30     # when the device reports no capacity
+
+
+def device_hbm_bytes() -> int:
+    """The first device's memory capacity, or the v5e's where the device
+    reports none (the CPU backend)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or V5E_HBM_BYTES)
+
+
+def gat_attention_path(heads: int, batch: int, m_pad: int, *,
+                       impl: str = "auto", mesh=None) -> str:
+    """``"dense_blocks"`` or ``"edges"`` for one GAT layer's attention.
+
+    Dense blocks only under ``impl="auto"`` with no ``mesh``, and only when
+    one f32 ``(heads·batch, m_pad, m_pad)`` block fits
+    :data:`GAT_BLOCK_HBM_SHARE` of :func:`device_hbm_bytes`. An explicit
+    ``impl`` or a ``mesh`` keeps the per-edge path, whose aggregation is
+    the SpMM that ``impl`` resolves."""
+    if impl != "auto" or mesh is not None:
+        return "edges"
+    block = 4 * heads * batch * m_pad * m_pad
+    cap = GAT_BLOCK_HBM_SHARE * device_hbm_bytes()
+    return "dense_blocks" if block <= cap else "edges"

@@ -183,9 +183,11 @@ def resolve_conv_impls(cfg: GCNConfig, batch: int, m_pad: int, nnz_pad: int,
 
     ``cfg.layer`` selects the workload shape (DESIGN.md §11): ``"gcn"``
     resolves the graph-conv LAYER workload (fused megakernel vs stacked
-    SpMM); ``"gat"`` resolves the attention aggregation, a plain SpMM with
-    the attention weights as scalar edge values over the head-flattened
-    batch (the full ladder); ``"rgcn"`` the ``(copy_lhs, mean)`` g-SpMM over
+    SpMM); ``"gat"`` resolves the edge path's attention aggregation, a
+    plain SpMM with the attention weights as scalar edge values over the
+    head-flattened batch (the full ladder) — the layer runs it only where
+    ``autotune.gat_attention_path`` does not pick dense blocks, so there
+    the decision names the fallback; ``"rgcn"`` the ``(copy_lhs, mean)`` g-SpMM over
     the relation-flattened batch (the g-SpMM-capable candidate subset)."""
     from repro import autotune
     from repro.kernels import resolve_interpret

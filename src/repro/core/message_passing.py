@@ -16,9 +16,12 @@ subset), mesh sharding via ``repro.distributed.spmm``.
 
 The model-zoo layers built on this primitive live in ``repro.models.gnn``:
 
-- ``gat_layer``  — multi-head attention: per-edge logits →
-  :func:`repro.kernels.segment_softmax.segment_softmax` → one vector-edge
-  ``(mul, sum)`` g-SpMM;
+- ``gat_layer``  — multi-head attention on one of two paths
+  (``repro.autotune.gat_attention_path``): dense per-(graph, head)
+  blocks where an f32 ``(heads·batch, m_pad, m_pad)`` block fits a
+  sixteenth of HBM under ``impl="auto"`` with no mesh, else per-edge
+  logits → :func:`repro.kernels.segment_softmax.segment_softmax` → one
+  scalar-edge ``batched_spmm`` over the head-flattened batch;
 - ``rgcn_layer`` — relation-batched weights via ``grouped_matmul`` + one
   ``(copy_lhs, mean)`` g-SpMM over the relation-flattened batch.
 """

@@ -16,7 +16,7 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from chipbench import reference_gat  # noqa: E402
-from repro.autotune import Workload  # noqa: E402
+from repro.autotune import Workload, gat_attention_path  # noqa: E402
 from repro.autotune.cost_model import estimate  # noqa: E402
 from repro.core.formats import BatchedCOO  # noqa: E402
 from repro.core.gcn import (  # noqa: E402
@@ -33,6 +33,7 @@ from repro.data.graphs import (  # noqa: E402
     ppi_like,
 )
 from repro.models.gnn import gat_layer, init_gat_layer  # noqa: E402
+from repro.observability import REGISTRY  # noqa: E402
 
 SEED = 2 ** 31 + 77
 # 2 graphs of at most 40 nodes, heads (2, 2, 3) of width 8; 10 directed
@@ -278,3 +279,174 @@ def test_auto_picks_on_ppi_layers_keep_ell_and_pallas_out(interpret):
                      k_pad=cfg.k_pad, n_b=width // heads, dtype="f32")
         for impl in ("ell", "pallas_ell", "pallas_gemm", "pallas_coo"):
             assert estimate(w, impl) == float("inf"), impl
+
+
+# --- the dense-block attention path (``impl="auto"``, no mesh) -------------
+
+def _layer_count(path):
+    return REGISTRY.counter("gat_attention_layers_total").value(path=path)
+
+
+def _awkward_batch():
+    """3 graphs, m_pad 24, nnz_pad 80: graph 0 repeats an edge, graph 1
+    has no edge at all, rows 5 and 21 have no incoming edge, and every
+    padded slot holds ids the softmax must ignore."""
+    rng = np.random.default_rng(11)
+    batch, m_pad, nnz_pad = 3, 24, 80
+    nnz = np.array([60, 0, 45], np.int32)
+    rows = rng.integers(0, m_pad, (batch, nnz_pad)).astype(np.int32)
+    cols = rng.integers(0, m_pad, (batch, nnz_pad)).astype(np.int32)
+    for b in range(batch):
+        r = rng.integers(0, 20, nnz[b])
+        rows[b, :nnz[b]] = np.where(r == 5, 6, r)
+        cols[b, :nnz[b]] = rng.integers(0, 22, nnz[b])
+    rows[0, 1], cols[0, 1] = rows[0, 0], cols[0, 0]
+    adj = BatchedCOO(jnp.asarray(rows), jnp.asarray(cols),
+                     jnp.ones((batch, nnz_pad), jnp.float32),
+                     jnp.asarray(nnz), jnp.full((batch,), m_pad, jnp.int32))
+    x = jnp.asarray(rng.standard_normal((batch, m_pad, 7)), jnp.float32)
+    return adj, x
+
+
+@pytest.mark.parametrize("heads, skip, mean_heads", [
+    (1, False, False), (4, True, False), (6, False, True), (4, True, True)])
+def test_dense_blocks_equal_the_edge_path(heads, skip, mean_heads):
+    adj, x = _awkward_batch()
+    p = init_gat_layer(jax.random.key(heads), 7, 6 * heads, heads, skip=skip)
+    p["b"] = jnp.linspace(-1.0, 1.0, 6 * heads)
+
+    def layer(impl):
+        return lambda q, x: gat_layer(q, adj, x, impl=impl,
+                                      mean_heads=mean_heads)
+
+    before = _layer_count("dense_blocks")
+    got = layer("auto")(p, x)
+    assert _layer_count("dense_blocks") == before + 1
+    want = layer("ref")(p, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    # no incoming edge (row 5, every row of graph 1): the bias and skip
+    base = jnp.broadcast_to(p["b"], got.shape[:2] + p["b"].shape) + (
+        x @ p["w_skip"] if skip else 0.0)
+    if mean_heads:
+        base = base.reshape(*x.shape[:2], heads, 6).mean(2)
+    np.testing.assert_allclose(np.asarray(got)[:, 5], np.asarray(base)[:, 5],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got)[1], np.asarray(base)[1],
+                               rtol=1e-6, atol=1e-6)
+    dy = jnp.asarray(np.random.default_rng(3).standard_normal(got.shape),
+                     jnp.float32)
+    g_got, g_want = (jax.grad(lambda q, x: jnp.sum(layer(i)(q, x) * dy),
+                              argnums=(0, 1))(p, x) for i in ("auto", "ref"))
+    assert all(bool(jnp.isfinite(v).all()) for v in jax.tree.leaves(g_got))
+    for k in p:
+        np.testing.assert_allclose(np.asarray(g_got[0][k]),
+                                   np.asarray(g_want[0][k]), rtol=1e-4,
+                                   atol=1e-5, err_msg=k)
+    np.testing.assert_allclose(np.asarray(g_got[1]), np.asarray(g_want[1]),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_a_dst_gradient_is_zero_where_no_logit_crosses_the_kink():
+    """With every logit on LeakyReLU's positive side the softmax cancels
+    ``s_dst`` outright, so ``a_dst``'s gradient is 0: the dense path gives
+    exactly 0, not the residue of a row sum."""
+    adj, _ = _awkward_batch()
+    x = jnp.abs(jnp.asarray(np.random.default_rng(4).standard_normal(
+        (3, 24, 7)), jnp.float32))
+    p = init_gat_layer(jax.random.key(9), 7, 16, 2)
+    p = dict(p, w=jnp.abs(p["w"]), a_src=jnp.abs(p["a_src"]),
+             a_dst=jnp.abs(p["a_dst"]))
+    dy = jnp.asarray(np.random.default_rng(5).standard_normal((3, 24, 16)),
+                     jnp.float32)
+    g = jax.grad(lambda q: jnp.sum(gat_layer(q, adj, x) * dy))(p)
+    assert not np.asarray(g["a_dst"]).any()
+    assert np.asarray(g["a_src"]).any()
+
+
+def test_ppi_layers_take_dense_blocks():
+    # the CPU reports no capacity, so the cap is the v5e's 16 GiB / 16
+    for heads in GCNConfig.ppi_gat().heads:
+        assert gat_attention_path(heads, 2, 3480) == "dense_blocks"
+
+
+def test_blocks_above_a_sixteenth_of_hbm_take_the_edges():
+    assert gat_attention_path(4, 2, 40_000) == "edges"
+
+
+def test_the_device_reported_capacity_sets_the_cap(monkeypatch):
+    class Device:
+        def memory_stats(self):
+            return {"bytes_limit": 8 * 2 ** 30}
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
+    # against 8 GiB / 16 = 537 MB
+    assert gat_attention_path(4, 2, 3480) == "dense_blocks"   # 388 MB
+    assert gat_attention_path(6, 2, 3480) == "edges"          # 581 MB
+
+
+@pytest.mark.parametrize("impl", ["ref", "csr", "dense"])
+def test_an_explicit_impl_keeps_the_edge_path(impl):
+    assert gat_attention_path(4, 2, 64, impl=impl) == "edges"
+    p, adj, x = _layer_inputs()
+    before = _layer_count("edges")
+    gat_layer(p, adj, x, impl=impl)
+    assert _layer_count("edges") == before + 1
+
+
+def test_a_mesh_keeps_the_edge_path():
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
+    assert gat_attention_path(4, 2, 64, mesh=mesh) == "edges"
+    p, adj, x = _layer_inputs()
+    before = _layer_count("edges")
+    got = gat_layer(p, adj, x, mesh=mesh)
+    assert _layer_count("edges") == before + 1
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(gat_layer(p, adj, x)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_counter_counts_traced_layers_not_calls(graphs):
+    b = _batch(graphs)
+    params = init_gcn(jax.random.key(SEED), CFG)
+    step = jax.jit(jax.grad(lambda p, x: gcn_loss(
+        p, CFG, b["adj"], x, b["n_nodes"], b["labels"])[0]))
+    before = (_layer_count("dense_blocks"), _layer_count("edges"))
+    for _ in range(3):
+        step(params, b["x"])
+    assert (_layer_count("dense_blocks"), _layer_count("edges")) \
+        == (before[0] + len(CFG.conv_widths), before[1])
+
+
+def test_the_dense_block_step_gathers_and_scatters_no_edge():
+    """The whole training gradient under ``auto`` keeps one scatter per
+    layer (the multiplicity block) and no gather; the edge path keeps the
+    per-edge gathers and the softmax's scatters."""
+    b = _batch(ppi_like(SPEC))
+    params = init_gcn(jax.random.key(SEED), CFG)
+
+    def primitives(cfg):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: gcn_loss(
+            p, cfg, b["adj"], b["x"], b["n_nodes"], b["labels"])[0]))(params)
+        names = []
+
+        def walk(j):
+            for e in j.eqns:
+                names.append(e.primitive.name)
+                for v in e.params.values():
+                    if isinstance(v, jax.extend.core.ClosedJaxpr):
+                        walk(v.jaxpr)
+                    elif isinstance(v, jax.extend.core.Jaxpr):
+                        walk(v)
+
+        walk(jaxpr.jaxpr)
+        return {n: names.count(n) for n in set(names)
+                if "gather" in n or "scatter" in n}
+
+    assert primitives(CFG) == {"scatter-add": len(CFG.conv_widths)}
+    edges = primitives(GCNConfig.ppi_gat(
+        n_features=6, conv_widths=(16, 16, 24), n_tasks=8, heads=(2, 2, 3),
+        impl="ref"))
+    assert edges["gather"] > 0 and edges["scatter-max"] == 3

@@ -13,6 +13,8 @@ every test module. The fixture skips where no topology can be described.
 Every call passes ``interpret=False``, so the kernels lower through Mosaic
 even though the test process runs on the CPU backend.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -215,3 +217,35 @@ def test_refused_impl_raises_when_compiled(impl):
     with pytest.raises(ValueError, match="does not compile for the TPU"):
         jax.eval_shape(lambda p, a, xx: graph_conv_batched(
             p, a, xx, impl=impl, k_pad=8, interpret=False), params, adj, x)
+
+
+def test_gat_dense_blocks_stay_fused_at_ppi_width(one_chip):
+    """The largest PPI GAT layer (6 heads of 121 over 1,024 inputs, 2
+    graphs of m_pad 3,480) on the dense-block path, forward and backward:
+    one scatter (the multiplicity block), no gather, and no row-wide
+    ``reduce-window``; XLA fuses each (heads, batch, m_pad, m_pad) block
+    into its producer and consumer, so the temporaries stay below one
+    block's 581 MB."""
+    from repro.models.gnn import gat_layer
+
+    b, m, nnz, heads, d, n_in = 2, 3480, 103704, 6, 121, 1024
+    ids = _spec((b, nnz), jnp.int32, one_chip)
+    counts = _spec((b,), jnp.int32, one_chip)
+    params = {"w": _spec((heads, n_in, d), jnp.float32, one_chip),
+              "a_src": _spec((heads, d), jnp.float32, one_chip),
+              "a_dst": _spec((heads, d), jnp.float32, one_chip),
+              "b": _spec((heads * d,), jnp.float32, one_chip)}
+
+    def loss(p, rids, cids, nnz, x):
+        a = BatchedCOO(rids, cids, jnp.ones(rids.shape), nnz, nnz)
+        y = gat_layer(p, a, x, mean_heads=True)
+        return jnp.sum(y * y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 4))).lower(
+        params, ids, ids, counts,
+        _spec((b, m, n_in), jnp.float32, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert re.findall(r"= \S+ (scatter|gather|reduce-window)\(", hlo) \
+        == ["scatter"]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * heads * b * m * m
